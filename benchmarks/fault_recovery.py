@@ -44,6 +44,7 @@ def _child_restore_bench(quick: bool) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import Session
+    from repro.launch.mesh import make_mesh
 
     n_leaves = 4 if quick else 8
     dim = (256, 1024) if quick else (1024, 2048)
@@ -54,8 +55,7 @@ def _child_restore_bench(quick: bool) -> dict:
     template = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                 for k, v in state.items()}
 
-    mesh_full = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    mesh_full = make_mesh((2, 2), ("data", "model"), devices=jax.devices())
 
     def shardings_for(mesh):
         return {k: NamedSharding(mesh, P(None, "model"))
@@ -108,17 +108,13 @@ def _child_restore_bench(quick: bool) -> dict:
 def _elastic(session, template, survivors, shardings_for, plan_only=False):
     if plan_only:
         # resolve the remesh geometry without paying a second read
-        import jax
         from repro.distributed.fault import plan_elastic_remesh
-        import numpy as np
+        from repro.launch.mesh import make_mesh
         meta = session.checkpoint.read_meta()
         plan = plan_elastic_remesh(tuple(meta["mesh"]["shape"]),
                                    tuple(meta["mesh"]["axes"]),
                                    len(survivors))
-        mesh = jax.sharding.Mesh(
-            np.asarray(survivors[:plan.new_device_count],
-                       dtype=object).reshape(plan.new_shape),
-            plan.axis_names)
+        mesh = make_mesh(plan.new_shape, plan.axis_names, devices=survivors)
 
         class _RM:
             pass
@@ -138,7 +134,7 @@ def _spawn_child(quick: bool) -> dict:
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", "")).strip()
     env[_CHILD_ENV] = "1"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"           # virtual CPU devices, never a chip
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [os.path.join(repo, "src"), repo,
                     env.get("PYTHONPATH", "")] if p)

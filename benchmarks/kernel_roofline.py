@@ -76,9 +76,12 @@ def run(quick: bool = True) -> dict:
 
     from repro.kernels import ops, ref, spectral_lossy as K
     from repro.kernels import paged_attention as PK
+    from repro.roofline.analysis import V5E
     from repro.roofline.kernels import kernel_report
 
     on_tpu = jax.default_backend() == "tpu"
+    # on the chip: its own peaks; off it, the mirrors price a v5e
+    kind = None if on_tpu else V5E
     n_blocks = 256 if quick else 4096          # 64K / 1M elements
     rng = np.random.default_rng(0)
     xb = jnp.asarray(rng.standard_normal((n_blocks, ref.BLOCK)), jnp.float32)
@@ -107,14 +110,16 @@ def run(quick: bool = True) -> dict:
                                                interpret=False), (q, s)),
         }
         for name, (fn, fargs) in cases.items():
-            reports[name] = kernel_report(fn, fargs, name=name, measure=True)
+            reports[name] = kernel_report(fn, fargs, name=name, measure=True,
+                                          device_kind=kind)
     else:
         mirrors = _mirrors()
         for name, fn in mirrors.items():
             fargs = {"hist_refine": (y, coarse),
                      "threshold_quant": (y, t),
                      "dequant_idct": (q, s)}.get(name, (xb,))
-            reports[name] = kernel_report(fn, fargs, name=name, measure=True)
+            reports[name] = kernel_report(fn, fargs, name=name, measure=True,
+                                          device_kind=kind)
         # tiled flat pass does the same arithmetic per element as the
         # global-accumulation pass; mirror cost is shared.
         import dataclasses
@@ -137,7 +142,7 @@ def run(quick: bool = True) -> dict:
     pa = kernel_report(
         _ft.partial(PK.paged_decode_attention, interpret=not on_tpu),
         (qq, kp, vp, table, lengths), name="paged_attention",
-        measure=on_tpu)
+        measure=on_tpu, device_kind=kind)
     if not on_tpu:
         pa.note = ((pa.note + "; ") if pa.note else "") + \
             "interpret-mode lowering: cost reflects the emulation, not the kernel"

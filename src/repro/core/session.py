@@ -1187,9 +1187,9 @@ class Session:
         if not elastic:
             return self.checkpoint.restore(template, step, shardings)
         import jax
-        import numpy as np
         from repro.distributed.fault import (ElasticRestore,
                                              plan_elastic_remesh)
+        from repro.launch.mesh import make_mesh
         devs = list(devices) if devices is not None else list(jax.devices())
         if old_shape is None or axis_names is None:
             meta = self.checkpoint.read_meta(step) or {}
@@ -1205,10 +1205,7 @@ class Session:
                 axis_names = tuple(mesh_meta["axes"])
         plan = plan_elastic_remesh(tuple(old_shape), tuple(axis_names),
                                    len(devs))
-        mesh = jax.sharding.Mesh(
-            np.asarray(devs[:plan.new_device_count],
-                       dtype=object).reshape(plan.new_shape),
-            plan.axis_names)
+        mesh = make_mesh(plan.new_shape, plan.axis_names, devices=devs)
         if make_shardings is not None:
             shardings = make_shardings(mesh)
         step, state = self.checkpoint.restore(template, step, shardings)
@@ -1326,7 +1323,8 @@ class Session:
                 "saves": len(reports),
                 "raw_bytes": sum(r.raw_bytes for r in reports),
                 "stored_bytes": sum(r.stored_bytes for r in reports),
-                "last_step": reports[-1].step if reports else None,
+                # async saves can commit out of order: newest by step
+                "last_step": max((r.step for r in reports), default=None),
                 "kept_steps": self.checkpoint.list_steps(),
             }
         return rep

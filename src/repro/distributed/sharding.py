@@ -19,45 +19,29 @@ import dataclasses
 from typing import Any, Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 PyTree = Any
 
 
-# -- JAX version compat -------------------------------------------------------
-# The production API surface (jax.shard_map / jax.set_mesh) landed after the
-# 0.4.x line; these wrappers lower to jax.experimental.shard_map and the
-# Mesh context manager on older releases so the same call sites run on both.
+# -- mesh-level wrappers ----------------------------------------------------
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, *,
               axis_names=None, check_vma: bool = False):
     """Partially-manual shard_map: manual over ``axis_names`` only."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset() if axis_names is None
-            else frozenset(mesh.axis_names) - frozenset(axis_names))
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def mesh_context(mesh: Mesh):
-    """Ambient-mesh context manager across JAX versions."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh   # Mesh is itself a context manager on older releases
+    """Ambient-mesh context manager."""
+    return jax.set_mesh(mesh)
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """Device-free AbstractMesh across the two constructor signatures."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))   # shape_tuple form
-    except TypeError:
-        return AbstractMesh(tuple(sizes), tuple(names))  # legacy form
+    """Device-free AbstractMesh (rule evaluation without devices)."""
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 # Baseline rules: logical axis -> mesh axis (or tuple of mesh axes), None = replicate.

@@ -267,6 +267,33 @@ def _compress_tree_packed(blocks: tuple, eps: float, interpret: bool,
     return tuple(qs), tuple(ss)
 
 
+def _packed_tree_args(flat, selected: list, eps: float):
+    """Blocks and static arguments of the fused tree dispatch: every
+    selected leaf blockized and padded to its pow2 bucket, plus the tiles.
+    Returns ``(args, keep_rows)``; ``args`` feed ``_compress_tree_packed``.
+    """
+    blocks, keep_rows = [], []
+    for i in selected:
+        xb, _ = ref.blockize(flat[i][1])
+        real = xb.shape[0] + ((-xb.shape[0]) % K.HIST_TILE)
+        keep_rows.append(real)
+        blocks.append(_pad_to_rows(xb, _bucket_rows(real)))
+    hist_tile, quant_tile = _tiles_for(max(b.shape[0] for b in blocks))
+    # tiles must never straddle leaves: clamp to the smallest bucket
+    # (both are powers of two, so the smaller divides every bucket).
+    hist_tile = min(hist_tile, min(b.shape[0] for b in blocks))
+    args = (tuple(blocks), float(eps), _interpret(), hist_tile, quant_tile)
+    return args, keep_rows
+
+
+def _selected_leaves(state, policy):
+    flat, treedef = jax.tree_util.tree_flatten_with_path(state)
+    selected = [i for i, (path, leaf) in enumerate(flat)
+                if leaf is not None and policy is not None
+                and policy(jax.tree_util.keystr(path))]
+    return flat, treedef, selected
+
+
 def spectral_compress_tree(state, eps: float = 1e-2,
                            policy=None, *, fused: bool = True):
     """Device stage of the hybrid checkpoint pipeline: lossy-compress every
@@ -284,25 +311,11 @@ def spectral_compress_tree(state, eps: float = 1e-2,
     zero pad blocks carry no energy (thresholds are unchanged) and are
     sliced off the result.
     """
-    flat, treedef = jax.tree_util.tree_flatten_with_path(state)
+    flat, treedef, selected = _selected_leaves(state, policy)
     new_leaves = [leaf for _, leaf in flat]
-    selected = [i for i, (path, leaf) in enumerate(flat)
-                if leaf is not None and policy is not None
-                and policy(jax.tree_util.keystr(path))]
     if fused and len(selected) > 1:
-        blocks, keep_rows = [], []
-        for i in selected:
-            xb, _ = ref.blockize(flat[i][1])
-            real = xb.shape[0] + ((-xb.shape[0]) % K.HIST_TILE)
-            keep_rows.append(real)
-            blocks.append(_pad_to_rows(xb, _bucket_rows(real)))
-        hist_tile, quant_tile = _tiles_for(max(b.shape[0] for b in blocks))
-        # tiles must never straddle leaves: clamp to the smallest bucket
-        # (both are powers of two, so the smaller divides every bucket).
-        hist_tile = min(hist_tile, min(b.shape[0] for b in blocks))
-        qs, scales = _compress_tree_packed(tuple(blocks), float(eps),
-                                           _interpret(),
-                                           hist_tile, quant_tile)
+        args, keep_rows = _packed_tree_args(flat, selected, eps)
+        qs, scales = _compress_tree_packed(*args)
         for i, q, scale, real in zip(selected, qs, scales, keep_rows):
             leaf = flat[i][1]
             new_leaves[i] = Compressed(q[:real], scale[:real],
@@ -312,6 +325,17 @@ def spectral_compress_tree(state, eps: float = 1e-2,
         for i in selected:
             new_leaves[i] = spectral_compress(flat[i][1], eps)
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
+
+
+def lower_compress_tree(state, eps: float = 1e-2, policy=None):
+    """The fused dispatch ``spectral_compress_tree`` runs for ``state``,
+    lowered (``.compile().as_text()`` shows whether the Pallas kernels or
+    the jnp oracle are in it)."""
+    flat, _, selected = _selected_leaves(state, policy)
+    if len(selected) < 2:
+        raise ValueError("the fused dispatch needs >= 2 selected leaves")
+    args, _ = _packed_tree_args(flat, selected, eps)
+    return _compress_tree_packed.lower(*args)
 
 
 def packed_tree_cache_size() -> int:

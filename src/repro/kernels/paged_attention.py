@@ -4,11 +4,12 @@ The jnp reference path in ``models/attention.paged_decode_attention``
 materializes the gathered cache — ``k_pages[page_table]`` allocates a
 (B, P, page_size, N, D) copy in HBM every decode step, i.e. the whole
 *logical* cache is re-written once per token just to feed one (B,1) query.
-This kernel keeps the pool in place: the grid is one program per request
-row, the page table rides in as scalar prefetch (available before the body
-runs, the standard paged-attention trick), and each program walks its own
-page chain with the flash-style online-softmax recurrence — live memory is
-one (page_size, N, D) tile per step instead of the gathered sequence.
+This kernel keeps the pool in place: the grid is (request row, page of its
+chain), the page table rides in as scalar prefetch (available before the
+body runs, the standard paged-attention trick) and picks which pool page
+the pipeline DMAs into VMEM for each step, and the flash-style
+online-softmax recurrence runs across the chain — live memory is one
+(page_size, N, D) tile per step instead of the gathered sequence.
 
 Positions past ``length`` are masked to NEG_INF exactly like the dense
 slab's padding, so scratch/stale pages never contribute. Numerics match the
@@ -31,46 +32,59 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+# the kernel computes in f32: on the TPU a default-precision f32 matmul may
+# round its operands to bf16 (the CPU interpreter ignores the setting)
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   *, pages_per_seq: int, page_size: int, n_kv: int,
-                   group: int, d_v: int):
-    b = pl.program_id(0)
+                   m_ref, l_ref, acc_ref, *, page_size: int, n_kv: int,
+                   group: int):
+    """Grid step (b, j): attend row b's query to page j of its chain.
+
+    The page arrives as a (page_size, N, D) VMEM block picked by the
+    scalar-prefetched page table, so the pipeline's DMA fetches page j + 1
+    while page j is computed; the online-softmax state lives in VMEM
+    scratch across the chain and the output is written after its last page.
+    """
+    b, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[b]
     hq = n_kv * group
     d_k = q_ref.shape[-1]
     scale = 1.0 / math.sqrt(d_k)
-    q3 = q_ref[0, 0].reshape(n_kv, group, d_k).astype(jnp.float32)
 
-    def body(j, carry):
-        m, l, acc = carry
-        page = table_ref[b * pages_per_seq + j]
-        k = k_ref[pl.ds(page, 1)][0].astype(jnp.float32)   # (PS, N, Dk)
-        v = v_ref[pl.ds(page, 1)][0].astype(jnp.float32)   # (PS, N, Dv)
-        # (N,G,D) x (PS,N,D) -> (N,G,PS), batched over kv heads
-        s = jax.lax.dot_general(
-            q3, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # (N,G,PS) x (PS,N,Dv) -> (N,G,Dv)
-        pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * corr + pv
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    m0 = jnp.full((n_kv, group, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv, group, 1), jnp.float32)
-    a0 = jnp.zeros((n_kv, group, d_v), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, pages_per_seq, body, (m0, l0, a0))
-    out = acc / jnp.maximum(l, 1e-37)
-    o_ref[0, 0] = out.reshape(hq, d_v).astype(o_ref.dtype)
+    q3 = q_ref[0, 0].astype(jnp.float32).reshape(n_kv, group, d_k)
+    k = k_ref[...].astype(jnp.float32)                    # (PS, N, Dk)
+    v = v_ref[...].astype(jnp.float32)                    # (PS, N, Dv)
+    m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+    # (N,G,D) x (PS,N,D) -> (N,G,PS), batched over kv heads
+    s = jax.lax.dot_general(
+        q3, k, (((2,), (2,)), ((0,), (1,))), precision=F32,
+        preferred_element_type=jnp.float32) * scale
+    pos = j * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, 1, page_size), 2)
+    s = jnp.where(pos < length, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+    # (N,G,PS) x (PS,N,Dv) -> (N,G,Dv)
+    pv = jax.lax.dot_general(
+        p, v, (((2,), (0,)), ((0,), (1,))), precision=F32,
+        preferred_element_type=jnp.float32)
+    acc_new = acc * corr + pv
+    m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        out = acc_new / jnp.maximum(l_new, 1e-37)
+        o_ref[0, 0] = out.reshape(hq, acc_new.shape[-1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -88,23 +102,34 @@ def paged_decode_attention(
     _, page_size, n_kv, d_k = k_pages.shape
     d_v = v_pages.shape[-1]
     pages_per_seq = page_table.shape[1]
-    kernel = functools.partial(
-        _decode_kernel, pages_per_seq=pages_per_seq, page_size=page_size,
-        n_kv=n_kv, group=hq // n_kv, d_v=d_v)
+    group = hq // n_kv
+    kernel = functools.partial(_decode_kernel, page_size=page_size,
+                               n_kv=n_kv, group=group)
+
+    def page_block(i, j, table, _lengths):
+        return (table[i * pages_per_seq + j], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,               # page table + lengths in SMEM
-        grid=(b,),
+        grid=(b, pages_per_seq),
         in_specs=[
-            pl.BlockSpec((1, 1, hq, d_k), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, 1, hq, d_k), lambda i, j, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((None, page_size, n_kv, d_k), page_block),
+            pl.BlockSpec((None, page_size, n_kv, d_v), page_block),
         ],
-        out_specs=pl.BlockSpec((1, 1, hq, d_v), lambda i, *_: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, hq, d_v), lambda i, j, *_: (i, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),
+            pltpu.VMEM((n_kv, group, d_v), jnp.float32),
+        ],
     )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, 1, hq, d_v), q.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.reshape(-1).astype(jnp.int32),
       length.astype(jnp.int32), q, k_pages, v_pages)

@@ -28,8 +28,13 @@ sort in the VPU. The TPU-native redesign (see kernels/ref.py for the oracle):
 
 Tiling: blocks are BLOCK=256 wide (2 x 128 lanes; the DCT matmul contraction
 dim is 256 — MXU-aligned). The flat histogram kernel uses a small block-tile
-(8) so its (elements x NBINS) one-hot stays ~4 MB in VMEM; quant/dequant
-kernels use 64-block tiles (64 x 256 f32 = 64 KB per operand). Every kernel
+(8) so its (NBINS x elements) one-hot stays ~4 MB in VMEM; quant/dequant
+kernels use 64-block tiles (64 x 256 f32 = 64 KB per operand). Layouts
+follow the TPU's block rules (last two block dims divisible by (8, 128) or
+whole): per-block thresholds, scales and coarse bins travel as (n_blocks, 1)
+columns in (tile, 1) blocks, histograms as (1, nbins) rows, per-tile
+histograms as (n_tiles, 1, nbins), and the one-hot contraction takes 2-D
+(1, N) x (nbins, N)^T operands. Every kernel
 takes a ``tile=`` override so ``ops.py`` can swap in an autotuned tile per
 power-of-two shape bucket; buffers whose block count is not a tile multiple
 are zero-padded up to it and the result sliced back (a prime block count must
@@ -40,8 +45,6 @@ unchanged; ``ops.py`` picks the mode from the backend.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -51,6 +54,9 @@ from repro.kernels.ref import (BLOCK, LOG2_HI, LOG2_LO, NBINS, NBINS_COARSE,
 
 HIST_TILE = 8      # blocks per grid step in the histogram passes
 QUANT_TILE = 64    # blocks per grid step in quant/dequant passes
+# f32 operands mean f32 math: on the TPU a default-precision f32 matmul may
+# round its operands to bf16 (the CPU interpreter ignores the setting)
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _check_blocks(xb: jax.Array, tile: int, name: str) -> None:
@@ -68,8 +74,7 @@ def _check_blocks(xb: jax.Array, tile: int, name: str) -> None:
 def _pad_rows(buf: jax.Array, pad: int, value: float = 0.0) -> jax.Array:
     if not pad:
         return buf
-    width = ((0, pad), (0, 0)) if buf.ndim == 2 else ((0, pad),)
-    return jnp.pad(buf, width, constant_values=value)
+    return jnp.pad(buf, ((0, pad), (0, 0)), constant_values=value)
 
 
 def _tile_and_pad(n_blocks: int, want: int) -> tuple[int, int]:
@@ -95,25 +100,72 @@ def _bin_idx(a: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _dct_and_bins(x_ref, d_ref, y_ref):
-    """Shared kernel prologue: DCT matmul + flat bin indices of the tile."""
+    """Shared kernel prologue: DCT matmul + flat bin indices of the tile,
+    flattened to one (1, TILE*BLOCK) row."""
     x = x_ref[...].astype(jnp.float32)          # (TILE, BLOCK)
     d = d_ref[...]                              # (BLOCK, BLOCK)
     y = jax.lax.dot_general(                    # y = x @ d.T   (MXU)
-        x, d, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        x, d, (((1,), (1,)), ((), ())), precision=F32,
+        preferred_element_type=jnp.float32)
     y_ref[...] = y
-    a = jnp.abs(y.reshape(-1))                  # (TILE*BLOCK,)
+    a = jnp.abs(y.reshape(1, -1))               # (1, TILE*BLOCK)
     return a * a, _bin_idx(a)
 
 
-def _onehot_hist(a2, idx, nbins):
-    """One-hot binning as matmul work (no scatter on the VPU)."""
-    bins = jax.lax.broadcasted_iota(jnp.int32, (a2.shape[0], nbins), 1)
-    onehot = (idx[:, None] == bins).astype(jnp.float32)
-    cnt = jnp.sum(onehot, axis=0)
-    eng = jax.lax.dot_general(
-        a2, onehot, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _onehot_hist(a2, idx, nbins, weight=None):
+    """One-hot binning as matmul work (no scatter on the VPU).
+
+    ``a2``/``idx`` (and the optional 0/1 ``weight``) are (1, N) rows. The
+    one-hot is built transposed, (nbins, N), so ``idx`` broadcasts down the
+    sublanes, and both sums are (1, N) x (nbins, N)^T contractions — the
+    2-D operand form the TPU's matmul lowering accepts. Returns (1, nbins)
+    counts and energies.
+    """
+    bins = jax.lax.broadcasted_iota(jnp.int32, (nbins, a2.shape[1]), 0)
+    onehot = (idx == bins).astype(jnp.float32)
+    if weight is not None:
+        onehot = onehot * weight
+    dims = (((1,), (1,)), ((), ()))
+    cnt = jax.lax.dot_general(jnp.ones_like(a2), onehot, dims,
+                              preferred_element_type=jnp.float32)
+    eng = jax.lax.dot_general(a2, onehot, dims, precision=F32,
+                              preferred_element_type=jnp.float32)
     return cnt, eng
+
+
+def _refine_bins(y_ref, c_ref):
+    """Refine-pass prologue: (1, N) energies, fine-bin indices and 0/1
+    membership of the elements that fall in their block's coarse bin."""
+    a = jnp.abs(y_ref[...])                      # (TILE, BLOCK)
+    idx = _bin_idx(a)                            # flat bins
+    c = c_ref[...]                               # (TILE, 1) coarse bin/block
+    member = (idx // NBINS_FINE) == c
+    fine = jnp.where(member, idx - c * NBINS_FINE, 0).reshape(1, -1)
+    w = member.astype(jnp.float32).reshape(1, -1)
+    return (a * a).reshape(1, -1), fine, w
+
+
+def _column(v: jax.Array, n_blocks: int, dtype) -> jax.Array:
+    """Scalar or per-block vector -> (n_blocks, 1) column. Per-block values
+    ride in (tile, 1) blocks: a rank-1 (tile,) block would have to be a
+    multiple of 128 lanes on the TPU."""
+    v = jnp.asarray(v, dtype)
+    if v.ndim == 0 or v.size == 1:
+        v = jnp.broadcast_to(v.reshape(()), (n_blocks,))
+    return v.reshape(n_blocks, 1)
+
+
+def _hist_shapes(nbins: int, n_tiles: int | None = None):
+    """Out specs/shapes of a histogram pair: one accumulated (1, nbins)
+    row, or one (1, nbins) row per grid step of an (n_tiles, 1, nbins)
+    array — both keep the last two block dims whole, as the TPU requires."""
+    if n_tiles is None:
+        spec = pl.BlockSpec((1, nbins), lambda i: (0, 0))
+        shape = jax.ShapeDtypeStruct((1, nbins), jnp.float32)
+    else:
+        spec = pl.BlockSpec((None, 1, nbins), lambda i: (i, 0, 0))
+        shape = jax.ShapeDtypeStruct((n_tiles, 1, nbins), jnp.float32)
+    return [spec, spec], [shape, shape]
 
 
 def _dct_hist_kernel(x_ref, d_ref, y_ref, cnt_ref, eng_ref):
@@ -130,6 +182,29 @@ def _dct_hist_kernel(x_ref, d_ref, y_ref, cnt_ref, eng_ref):
     eng_ref[...] += eng
 
 
+def _dct_call(kernel, xb: jax.Array, tile: int, nbins: int, *,
+              tiled: bool, interpret: bool):
+    """DCT + histogram pallas_call shared by the four histogram passes."""
+    n_blocks = xb.shape[0]
+    n_tiles = n_blocks // tile
+    d = jnp.asarray(dct_matrix(BLOCK))
+    h_specs, h_shapes = _hist_shapes(nbins, n_tiles if tiled else None)
+    y, cnt, eng = pl.pallas_call(
+        kernel,
+        grid=(n_tiles,),
+        in_specs=[
+            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
+            pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
+        ],
+        out_specs=[pl.BlockSpec((tile, BLOCK), lambda i: (i, 0))] + h_specs,
+        out_shape=[jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32)]
+        + h_shapes,
+        interpret=interpret,
+    )(xb, d)
+    rows = (n_tiles, nbins) if tiled else (nbins,)
+    return y, cnt.reshape(rows), eng.reshape(rows)
+
+
 def dct_hist(xb: jax.Array, *, interpret: bool = True,
              tile: int | None = None):
     """xb: (n_blocks, BLOCK) f32 -> (y, counts, energies).
@@ -139,28 +214,8 @@ def dct_hist(xb: jax.Array, *, interpret: bool = True,
     """
     tile = tile or HIST_TILE
     _check_blocks(xb, tile, "dct_hist")
-    n_blocks = xb.shape[0]
-    d = jnp.asarray(dct_matrix(BLOCK))
-    grid = (n_blocks // tile,)
-    return pl.pallas_call(
-        _dct_hist_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((NBINS,), lambda i: (0,)),
-            pl.BlockSpec((NBINS,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((NBINS,), jnp.float32),
-            jax.ShapeDtypeStruct((NBINS,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xb, d)
+    return _dct_call(_dct_hist_kernel, xb, tile, NBINS, tiled=False,
+                     interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -191,27 +246,8 @@ def dct_hist_coarse(xb: jax.Array, *, interpret: bool = True,
     """
     tile = tile or HIST_TILE
     _check_blocks(xb, tile, "dct_hist_coarse")
-    n_blocks = xb.shape[0]
-    d = jnp.asarray(dct_matrix(BLOCK))
-    return pl.pallas_call(
-        _dct_hist_coarse_kernel,
-        grid=(n_blocks // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((NBINS_COARSE,), lambda i: (0,)),
-            pl.BlockSpec((NBINS_COARSE,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((NBINS_COARSE,), jnp.float32),
-            jax.ShapeDtypeStruct((NBINS_COARSE,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xb, d)
+    return _dct_call(_dct_hist_coarse_kernel, xb, tile, NBINS_COARSE,
+                     tiled=False, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -226,20 +262,31 @@ def _hist_refine_kernel(y_ref, c_ref, cnt_ref, eng_ref):
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
         eng_ref[...] = jnp.zeros_like(eng_ref)
 
-    y = y_ref[...]                               # (TILE, BLOCK)
-    a = jnp.abs(y)
-    idx = _bin_idx(a)                            # (TILE, BLOCK) flat bins
-    c = c_ref[...][:, None]                      # (TILE, 1) coarse bin/block
-    member = (idx // NBINS_FINE) == c
-    fine = jnp.where(member, idx - c * NBINS_FINE, 0).reshape(-1)
-    w = member.astype(jnp.float32).reshape(-1)
-    a2 = (a * a).reshape(-1)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (fine.shape[0], NBINS_FINE), 1)
-    onehot = (fine[:, None] == bins).astype(jnp.float32) * w[:, None]
-    cnt_ref[...] += jnp.sum(onehot, axis=0)
-    eng_ref[...] += jax.lax.dot_general(
-        a2, onehot, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    a2, fine, w = _refine_bins(y_ref, c_ref)
+    cnt, eng = _onehot_hist(a2, fine, NBINS_FINE, w)
+    cnt_ref[...] += cnt
+    eng_ref[...] += eng
+
+
+def _refine_call(kernel, y: jax.Array, coarse: jax.Array, tile: int, *,
+                 tiled: bool, interpret: bool):
+    """Refine-histogram pallas_call shared by the two refine passes."""
+    n_blocks = y.shape[0]
+    n_tiles = n_blocks // tile
+    h_specs, h_shapes = _hist_shapes(NBINS_FINE, n_tiles if tiled else None)
+    cnt, eng = pl.pallas_call(
+        kernel,
+        grid=(n_tiles,),
+        in_specs=[
+            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+        ],
+        out_specs=h_specs,
+        out_shape=h_shapes,
+        interpret=interpret,
+    )(y, _column(coarse, n_blocks, jnp.int32))
+    rows = (n_tiles, NBINS_FINE) if tiled else (NBINS_FINE,)
+    return cnt.reshape(rows), eng.reshape(rows)
 
 
 def hist_refine(y: jax.Array, coarse: jax.Array, *, interpret: bool = True,
@@ -255,27 +302,8 @@ def hist_refine(y: jax.Array, coarse: jax.Array, *, interpret: bool = True,
     """
     tile = tile or HIST_TILE
     _check_blocks(y, tile, "hist_refine")
-    n_blocks = y.shape[0]
-    coarse = jnp.asarray(coarse, jnp.int32)
-    if coarse.ndim == 0 or coarse.size == 1:
-        coarse = jnp.broadcast_to(coarse.reshape(()), (n_blocks,))
-    return pl.pallas_call(
-        _hist_refine_kernel,
-        grid=(n_blocks // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((NBINS_FINE,), lambda i: (0,)),
-            pl.BlockSpec((NBINS_FINE,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((NBINS_FINE,), jnp.float32),
-            jax.ShapeDtypeStruct((NBINS_FINE,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(y, coarse)
+    return _refine_call(_hist_refine_kernel, y, coarse, tile, tiled=False,
+                        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -291,9 +319,7 @@ def hist_refine(y: jax.Array, coarse: jax.Array, *, interpret: bool = True,
 
 def _dct_hist_tiled_kernel(x_ref, d_ref, y_ref, cnt_ref, eng_ref):
     a2, idx = _dct_and_bins(x_ref, d_ref, y_ref)
-    cnt, eng = _onehot_hist(a2, idx, NBINS)
-    cnt_ref[...] = cnt[None]
-    eng_ref[...] = eng[None]
+    cnt_ref[...], eng_ref[...] = _onehot_hist(a2, idx, NBINS)
 
 
 def dct_hist_tiled(xb: jax.Array, *, interpret: bool = True,
@@ -301,35 +327,14 @@ def dct_hist_tiled(xb: jax.Array, *, interpret: bool = True,
     """xb: (n_blocks, BLOCK) f32 -> (y, counts (n_tiles, NBINS), energies)."""
     tile = tile or HIST_TILE
     _check_blocks(xb, tile, "dct_hist_tiled")
-    n_blocks = xb.shape[0]
-    d = jnp.asarray(dct_matrix(BLOCK))
-    n_tiles = n_blocks // tile
-    return pl.pallas_call(
-        _dct_hist_tiled_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, NBINS), lambda i: (i, 0)),
-            pl.BlockSpec((1, NBINS), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, NBINS), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, NBINS), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xb, d)
+    return _dct_call(_dct_hist_tiled_kernel, xb, tile, NBINS, tiled=True,
+                     interpret=interpret)
 
 
 def _dct_hist_coarse_tiled_kernel(x_ref, d_ref, y_ref, cnt_ref, eng_ref):
     a2, idx = _dct_and_bins(x_ref, d_ref, y_ref)
-    cnt, eng = _onehot_hist(a2, idx // NBINS_FINE, NBINS_COARSE)
-    cnt_ref[...] = cnt[None]
-    eng_ref[...] = eng[None]
+    cnt_ref[...], eng_ref[...] = _onehot_hist(a2, idx // NBINS_FINE,
+                                              NBINS_COARSE)
 
 
 def dct_hist_coarse_tiled(xb: jax.Array, *, interpret: bool = True,
@@ -342,45 +347,13 @@ def dct_hist_coarse_tiled(xb: jax.Array, *, interpret: bool = True,
     """
     tile = tile or HIST_TILE
     _check_blocks(xb, tile, "dct_hist_coarse_tiled")
-    n_blocks = xb.shape[0]
-    d = jnp.asarray(dct_matrix(BLOCK))
-    n_tiles = n_blocks // tile
-    return pl.pallas_call(
-        _dct_hist_coarse_tiled_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, NBINS_COARSE), lambda i: (i, 0)),
-            pl.BlockSpec((1, NBINS_COARSE), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, NBINS_COARSE), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, NBINS_COARSE), jnp.float32),
-        ],
-        interpret=interpret,
-    )(xb, d)
+    return _dct_call(_dct_hist_coarse_tiled_kernel, xb, tile, NBINS_COARSE,
+                     tiled=True, interpret=interpret)
 
 
 def _hist_refine_tiled_kernel(y_ref, c_ref, cnt_ref, eng_ref):
-    y = y_ref[...]
-    a = jnp.abs(y)
-    idx = _bin_idx(a)
-    c = c_ref[...][:, None]
-    member = (idx // NBINS_FINE) == c
-    fine = jnp.where(member, idx - c * NBINS_FINE, 0).reshape(-1)
-    w = member.astype(jnp.float32).reshape(-1)
-    a2 = (a * a).reshape(-1)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (fine.shape[0], NBINS_FINE), 1)
-    onehot = (fine[:, None] == bins).astype(jnp.float32) * w[:, None]
-    cnt_ref[...] = jnp.sum(onehot, axis=0)[None]
-    eng_ref[...] = jax.lax.dot_general(
-        a2, onehot, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[None]
+    a2, fine, w = _refine_bins(y_ref, c_ref)
+    cnt_ref[...], eng_ref[...] = _onehot_hist(a2, fine, NBINS_FINE, w)
 
 
 def hist_refine_tiled(y: jax.Array, coarse: jax.Array, *,
@@ -394,28 +367,8 @@ def hist_refine_tiled(y: jax.Array, coarse: jax.Array, *,
     """
     tile = tile or HIST_TILE
     _check_blocks(y, tile, "hist_refine_tiled")
-    n_blocks = y.shape[0]
-    n_tiles = n_blocks // tile
-    coarse = jnp.asarray(coarse, jnp.int32)
-    if coarse.ndim == 0 or coarse.size == 1:
-        coarse = jnp.broadcast_to(coarse.reshape(()), (n_blocks,))
-    return pl.pallas_call(
-        _hist_refine_tiled_kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, NBINS_FINE), lambda i: (i, 0)),
-            pl.BlockSpec((1, NBINS_FINE), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_tiles, NBINS_FINE), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles, NBINS_FINE), jnp.float32),
-        ],
-        interpret=interpret,
-    )(y, coarse)
+    return _refine_call(_hist_refine_tiled_kernel, y, coarse, tile,
+                        tiled=True, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -424,11 +377,11 @@ def hist_refine_tiled(y: jax.Array, coarse: jax.Array, *,
 
 def _threshold_quant_kernel(y_ref, t_ref, q_ref, s_ref):
     y = y_ref[...]                               # (TILE, BLOCK) f32
-    t = t_ref[...][:, None]                      # (TILE, 1) per-block threshold
+    t = t_ref[...]                               # (TILE, 1) block thresholds
     kept = jnp.where(jnp.abs(y) >= t, y, 0.0)
-    amax = jnp.max(jnp.abs(kept), axis=-1)       # (TILE,)
+    amax = jnp.max(jnp.abs(kept), axis=-1, keepdims=True)   # (TILE, 1)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(kept / scale[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(kept / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale.astype(jnp.float32)
 
@@ -449,29 +402,26 @@ def threshold_quant(y: jax.Array, t: jax.Array, *, interpret: bool = True,
             f"got shape {tuple(y.shape)}")
     n_blocks = y.shape[0]
     tile, pad = _tile_and_pad(n_blocks, tile or QUANT_TILE)
-    t = jnp.asarray(t, jnp.float32)
-    if t.ndim == 0 or t.size == 1:
-        t = jnp.broadcast_to(t.reshape(()), (n_blocks,))
+    t = _pad_rows(_column(t, n_blocks, jnp.float32), pad)
     y = _pad_rows(y, pad)
-    t = _pad_rows(t, pad)
     q, s = pl.pallas_call(
         _threshold_quant_kernel,
         grid=((n_blocks + pad) // tile,),
         in_specs=[
             pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_blocks + pad, BLOCK), jnp.int8),
-            jax.ShapeDtypeStruct((n_blocks + pad,), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks + pad, 1), jnp.float32),
         ],
         interpret=interpret,
     )(y, t)
-    return (q[:n_blocks], s[:n_blocks]) if pad else (q, s)
+    return q[:n_blocks], s[:n_blocks, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -479,9 +429,9 @@ def threshold_quant(y: jax.Array, t: jax.Array, *, interpret: bool = True,
 # ---------------------------------------------------------------------------
 
 def _dequant_idct_kernel(q_ref, s_ref, d_ref, x_ref):
-    y = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+    y = q_ref[...].astype(jnp.float32) * s_ref[...]      # s: (TILE, 1)
     x_ref[...] = jax.lax.dot_general(            # x = y @ d    (MXU)
-        y, d_ref[...], (((1,), (0,)), ((), ())),
+        y, d_ref[...], (((1,), (0,)), ((), ())), precision=F32,
         preferred_element_type=jnp.float32)
 
 
@@ -495,13 +445,14 @@ def dequant_idct(q: jax.Array, scale: jax.Array, *, interpret: bool = True,
     tile, pad = _tile_and_pad(n_blocks, tile or QUANT_TILE)
     d = jnp.asarray(dct_matrix(BLOCK))
     q = _pad_rows(q, pad)
-    scale = _pad_rows(scale, pad, 1.0)   # pad rows dequantize 0*1 -> 0
+    # pad rows dequantize 0*1 -> 0
+    scale = _pad_rows(_column(scale, n_blocks, jnp.float32), pad, 1.0)
     x = pl.pallas_call(
         _dequant_idct_kernel,
         grid=((n_blocks + pad) // tile,),
         in_specs=[
             pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK, BLOCK), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile, BLOCK), lambda i: (i, 0)),

@@ -201,7 +201,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
                                                    cache_bytes=cache_b)
     report = roofline.analyze(
         arch=arch, shape=shape_name, mesh_desc=_mesh_desc(mesh), chips=chips,
-        cost=cost, hlo_text=hlo,
+        device_kind=roofline.V5E, cost=cost, hlo_text=hlo,
         model_flops_global=roofline.model_flops(cfg, shape),
         memory_stats=mem_stats, default_group=_default_group(mesh),
         wire_bytes_override=wire_override,

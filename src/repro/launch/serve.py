@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import base as configs
 from repro.core import InSituPlan, Session, Telemetry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as P_lib
 from repro.models import transformer
 from repro.serving.engine import Request, ServingEngine
@@ -65,6 +66,8 @@ def default_serve_plan(*, insitu_mode: str = "async",
 
 
 def serve_loop(arch: str, *, n_requests: int = 8, max_new: int = 8,
+               smoke: bool = True, prompt_len: Optional[int] = None,
+               max_len: Optional[int] = None,
                slots: int = 4, insitu_mode: str = "async",
                seed: int = 0, plan: Optional[Any] = None,
                engine_kind: str = "paged", num_pages: int = 17,
@@ -75,18 +78,24 @@ def serve_loop(arch: str, *, n_requests: int = 8, max_new: int = 8,
     ``prefix_len > 0`` gives every request a common ``prefix_len``-token
     system prompt, registered on the paged engine so matching admits map
     the shared chain copy-on-write and prefill only their own suffix.
+    ``smoke=False`` serves the arch at its published widths; ``prompt_len``
+    (the engine's prompt width and the length of each unique prompt,
+    default 16, or the shared prefix + 8) and ``max_len`` (per-request KV
+    capacity, a multiple of ``page_size``) size the engine for real prompts.
     ``hydrate_from`` (a chain directory, ``tcp://host:port`` listen
     address, or ``SnapshotStore``) skips cold start entirely: the paged
     engine is rebuilt from the snapshot chain — pool, tables, allocator,
     prefixes, in-flight requests — and keeps serving from there.
     """
-    cfg = configs.get(arch, smoke=True)
+    cfg = configs.get(arch, smoke=smoke)
     params = P_lib.materialize(jax.random.PRNGKey(seed),
                                transformer.param_spec(cfg))
     hydrate_info = None
-    prompt_len = max(16, prefix_len + 8)
-    max_len = max(64, ((prompt_len + max_new + page_size - 1)
-                       // page_size) * page_size)
+    if prompt_len is None:
+        prompt_len = max(16, prefix_len + 8)
+    if max_len is None:
+        max_len = max(64, ((prompt_len + max_new + page_size - 1)
+                           // page_size) * page_size)
     if engine_kind == "paged":
         if hydrate_from is not None:
             from repro.launch.hydrate import ReplicaHydrator
@@ -138,7 +147,7 @@ def serve_loop(arch: str, *, n_requests: int = 8, max_new: int = 8,
             engine.register_prefix(prefix)
     else:
         requests = [
-            Request(i, rng.integers(0, cfg.vocab_size, size=16),
+            Request(i, rng.integers(0, cfg.vocab_size, size=prompt_len),
                     max_new=max_new)
             for i in range(n_requests)]
 
@@ -171,7 +180,7 @@ def serve_loop(arch: str, *, n_requests: int = 8, max_new: int = 8,
     done = sum(1 for r in requests if r.done)
     toks = sum(len(r.out) for r in requests) - carried_toks
     rep = session.report()
-    prefix_stats = None
+    prefix_stats = ps = None
     if engine_kind == "paged":
         ps = engine.page_stats()
         log(f"page pool: {ps['used_pages']}/{ps['num_pages'] - 1} pages "
@@ -200,7 +209,8 @@ def serve_loop(arch: str, *, n_requests: int = 8, max_new: int = 8,
     return {"requests": requests, "telemetry": tm, "steps": step,
             "insitu_results": len(session.results),
             "session_report": rep, "tok_per_s": toks / total,
-            "prefix_stats": prefix_stats, "hydrate_info": hydrate_info}
+            "prefix_stats": prefix_stats, "page_stats": ps,
+            "hydrate_info": hydrate_info}
 
 
 def main() -> None:
@@ -236,6 +246,7 @@ def main() -> None:
                          "tcp://host:port address to listen on for a "
                          "producer's mirrored frames")
     args = ap.parse_args()
+    enable_compile_cache()
     plan = default_serve_plan(insitu_mode=args.insitu,
                               base_every=args.snapshot_base_every,
                               snapshot_dir=args.snapshot_dir,

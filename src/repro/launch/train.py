@@ -34,6 +34,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import optim
 from repro.configs import base as configs
 from repro.distributed import sharding
+from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import params as P_lib
 from repro.models import transformer
 from repro.optim import grad_compress
@@ -338,6 +340,7 @@ def default_train_plan(*, insitu_mode: str = "async",
 
 
 def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
+               shape: Optional[configs.ShapeConfig] = None,
                insitu_mode: str = "async", ckpt_dir: Optional[str] = None,
                ckpt_every: int = 20, seed: int = 0,
                analytics_every: int = 10, p_i: int = 2,
@@ -357,14 +360,20 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
     via ``PipelineRuntime.inject_sink_fault`` (transient-failure drills).
     ``on_session`` runs once with the live session before the first step
     (e.g. to grab a task's transport sink for a network-fault drill).
+    ``shape`` sets sequence length and global batch (default: the smoke
+    shape, or the production-mesh ``train_4k`` when ``smoke=False``).
+    Returns the per-step losses and host-clock step times (each ends in
+    the loss's device-to-host read), the final state, and the session's
+    telemetry and reports.
     """
     from repro.core import InSituPlan, Session, Telemetry
     from repro.data.pipeline import Prefetcher, batch_spec_for
     from repro.distributed.fault import StragglerMonitor
 
     cfg = configs.get(arch, smoke=smoke)
-    shape = configs.SMOKE_SHAPE if smoke else configs.SHAPES["train_4k"]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    if shape is None:
+        shape = configs.SMOKE_SHAPE if smoke else configs.SHAPES["train_4k"]
+    mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
     step_cfg = StepConfig()
     tm = Telemetry()
 
@@ -393,14 +402,16 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
             if session.latest_checkpoint_step() is not None:
                 start, state = session.restore(state)
                 log(f"resumed from step {start}")
+            # jit does not reshard a committed argument to its in_shardings:
+            # place the fresh or restored state on the step's layout
+            state = jax.device_put(state, st_sh)
 
             pf = Prefetcher(batch_spec_for(cfg, shape), depth=2,
                             telemetry=tm)
             mon = StragglerMonitor()
-            losses = []
+            losses, step_times = [], []
             for i in range(steps):
-                batch_np = next(pf)
-                batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                batch = jax.device_put(next(pf), b_sh)
                 t0 = time.perf_counter()
                 with session.step_span(i):
                     state, metrics = jitted(state, batch)
@@ -408,6 +419,7 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
                 step_s = time.perf_counter() - t0
                 mon.observe(0, step_s)
                 losses.append(loss)
+                step_times.append(step_s)
                 if "health" in session.streams:
                     # single-process loop: host 0's beat + step time; a
                     # multi-host launcher emits {"hosts": {h: s}} instead
@@ -426,7 +438,8 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
                         f"lr {float(metrics['lr']):.2e}")
             pf.close()
     n_analytics = sum(1 for r in session.results if r.task == "analytics")
-    return {"losses": losses, "telemetry": tm,
+    return {"losses": losses, "step_s": step_times, "state": state,
+            "telemetry": tm,
             "insitu_results": n_analytics,
             "session_report": session.report(),
             "straggler_report": mon.report()}
@@ -441,10 +454,22 @@ def main() -> None:
                     choices=["sync", "async", "hybrid"])
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--full", action="store_true",
-                    help="full (non-smoke) config — production mesh only")
+                    help="full (non-smoke) config at its published widths")
+    ap.add_argument("--seq-len", type=int, default=None,
+                    help="sequence length (with --global-batch; default: "
+                         "the smoke shape, or train_4k with --full)")
+    ap.add_argument("--global-batch", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
+    shape = None
+    if args.seq_len or args.global_batch:
+        if not (args.seq_len and args.global_batch):
+            ap.error("--seq-len and --global-batch go together")
+        shape = configs.ShapeConfig("cli", "train", args.seq_len,
+                                    args.global_batch)
     out = train_loop(args.arch, steps=args.steps, smoke=not args.full,
-                     insitu_mode=args.insitu, ckpt_dir=args.ckpt_dir)
+                     shape=shape, insitu_mode=args.insitu,
+                     ckpt_dir=args.ckpt_dir)
     print("final loss:", out["losses"][-1])
     print("in-situ results:", out["insitu_results"])
 

@@ -88,7 +88,11 @@ def flash_attention(
             if window:
                 mask &= q_pos[:, None] - kv_pos[None, :] < window
             s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # the running max only shifts the exponent and cancels in
+            # acc / l, so it carries no gradient; differentiating through
+            # the max gave NaN dq/dk for bf16 inputs on a TPU v5e
+            m_new = jax.lax.stop_gradient(
+                jnp.maximum(m, jnp.max(s, axis=-1)))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1)

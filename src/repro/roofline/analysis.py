@@ -17,8 +17,9 @@ apply the standard ring-algorithm wire multiplier:
   all-to-all       s * (g-1)/g
   collective-permute  s                 (one hop)
 
-Hardware constants are TPU v5e-class per chip: 197 TFLOP/s bf16, 819 GB/s
-HBM, ~50 GB/s/link ICI.
+Hardware peaks come from one table keyed by the JAX ``device_kind`` of the
+chip the program runs on (or is modelled for); a kind without a row is an
+error, never a default.
 """
 from __future__ import annotations
 
@@ -27,9 +28,38 @@ import re
 from dataclasses import dataclass, field, asdict
 from typing import Any, Optional
 
-PEAK_FLOPS_BF16 = 197e12
-HBM_BW = 819e9
-ICI_LINK_BW = 50e9
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops_bf16: float        # FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    ici_link_bw: float       # bytes/s per ICI link
+    source: str
+
+    @property
+    def ridge_intensity(self) -> float:
+        """FLOPs per HBM byte at the roofline's knee."""
+        return self.flops_bf16 / self.hbm_bw
+
+
+V5E = "TPU v5 lite"          # jax.Device.device_kind of a TPU v5e chip
+
+PEAKS: dict[str, ChipPeaks] = {
+    # 1,600 Gbit/s of chip-to-chip interconnect over 4 links = 50 GB/s each
+    V5E: ChipPeaks(197e12, 819e9, 50e9,
+                   'Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; raises for a kind with no row."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -149,6 +179,7 @@ class RooflineReport:
     model_flops_global: float
     useful_flops_ratio: float          # MODEL_FLOPS / (HLO_FLOPs * chips)
     bottleneck: str
+    device_kind: str                   # whose peaks price the terms
     hlo_memory_s: float = 0.0          # unfused upper bound, reference only
     model_bytes_per_device: float = 0.0
     collectives_by_kind: dict = field(default_factory=dict)
@@ -163,7 +194,8 @@ class RooflineReport:
         """useful-compute time / modelled step time (MFU-like, structural)."""
         if self.step_s <= 0 or self.chips <= 0:
             return 0.0
-        ideal = self.model_flops_global / (self.chips * PEAK_FLOPS_BF16)
+        ideal = self.model_flops_global / (
+            self.chips * peaks(self.device_kind).flops_bf16)
         return ideal / self.step_s
 
     def to_json(self) -> str:
@@ -174,7 +206,8 @@ class RooflineReport:
 
 
 def analyze(*, arch: str, shape: str, mesh_desc: str, chips: int,
-            cost: dict, hlo_text: str, model_flops_global: float,
+            device_kind: str, cost: dict, hlo_text: str,
+            model_flops_global: float,
             memory_stats: Optional[dict] = None,
             default_group: int = 1,
             wire_bytes_override: Optional[float] = None,
@@ -200,12 +233,13 @@ def analyze(*, arch: str, shape: str, mesh_desc: str, chips: int,
         note = (f"{len(unknown)} collective op(s) with unknown dtype(s) "
                 f"[{dts}] counted with zero wire bytes — collective term "
                 "is a lower bound")
-    compute_s = flops / PEAK_FLOPS_BF16
-    hlo_memory_s = byts / HBM_BW
+    chip = peaks(device_kind)
+    compute_s = flops / chip.flops_bf16
+    hlo_memory_s = byts / chip.hbm_bw
     mem_bytes = (model_bytes_per_device if model_bytes_per_device is not None
                  else byts)
-    memory_s = mem_bytes / HBM_BW
-    collective_s = wire / ICI_LINK_BW
+    memory_s = mem_bytes / chip.hbm_bw
+    collective_s = wire / chip.ici_link_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
@@ -216,6 +250,7 @@ def analyze(*, arch: str, shape: str, mesh_desc: str, chips: int,
         wire_bytes_per_device=wire, compute_s=compute_s, memory_s=memory_s,
         collective_s=collective_s, model_flops_global=model_flops_global,
         useful_flops_ratio=useful, bottleneck=bottleneck,
+        device_kind=device_kind,
         hlo_memory_s=hlo_memory_s,
         model_bytes_per_device=float(mem_bytes),
         collectives_by_kind=by_kind, memory_per_device_bytes=memory_stats,

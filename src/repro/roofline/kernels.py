@@ -2,11 +2,11 @@
 
 ``kernel_report(fn, args)`` lowers + compiles ``fn`` with ``jax.jit`` and
 reads ``cost_analysis()`` flops / bytes-accessed to place the kernel on the
-single-chip compute/memory roofline (same hardware constants as the step
-roofline in ``analysis.py``):
+single-chip compute/memory roofline of the chip it runs on, or of
+``device_kind`` (peaks from the table in ``analysis.py``):
 
-  compute_s = flops / PEAK_FLOPS_BF16
-  memory_s  = bytes / HBM_BW
+  compute_s = flops / peak bf16 FLOP/s
+  memory_s  = bytes / HBM bytes/s
   bound     = whichever ceiling is higher; intensity vs the ridge point
               (peak_flops / hbm_bw) tells the same story per byte.
 
@@ -25,9 +25,7 @@ from typing import Optional
 
 import jax
 
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS_BF16
-
-RIDGE_INTENSITY = PEAK_FLOPS_BF16 / HBM_BW     # flops/byte at the roof knee
+from repro.roofline.analysis import peaks
 
 
 @dataclass
@@ -67,10 +65,13 @@ def _cost_dict(compiled) -> dict:
 
 
 def kernel_report(fn, args, *, name: str = "", measure: bool = False,
-                  iters: int = 3,
+                  iters: int = 3, device_kind: Optional[str] = None,
                   flops_override: Optional[float] = None,
                   bytes_override: Optional[float] = None) -> KernelReport:
-    """Compile ``fn(*args)`` and place it on the compute/memory roofline."""
+    """Compile ``fn(*args)`` and place it on the compute/memory roofline
+    of ``device_kind`` (default: the kind of ``jax.devices()[0]``, which
+    raises off a chip with published peaks)."""
+    chip = peaks(device_kind or jax.devices()[0].device_kind)
     jfn = jax.jit(fn)
     compiled = jfn.lower(*args).compile()
     cost = _cost_dict(compiled)
@@ -79,8 +80,8 @@ def kernel_report(fn, args, *, name: str = "", measure: bool = False,
                   else flops_override)
     byts = float(cost.get("bytes accessed", 0.0) if bytes_override is None
                  else bytes_override)
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
+    compute_s = flops / chip.flops_bf16
+    memory_s = byts / chip.hbm_bw
     roofline_s = max(compute_s, memory_s)
     intensity = flops / byts if byts > 0 else 0.0
     measured = None
@@ -100,7 +101,7 @@ def kernel_report(fn, args, *, name: str = "", measure: bool = False,
     return KernelReport(
         name=name or getattr(fn, "__name__", "kernel"),
         flops=flops, bytes_accessed=byts, intensity=intensity,
-        ridge_intensity=RIDGE_INTENSITY, compute_s=compute_s,
+        ridge_intensity=chip.ridge_intensity, compute_s=compute_s,
         memory_s=memory_s, roofline_s=roofline_s,
         bound="compute" if compute_s >= memory_s else "memory",
         measured_s=measured, achieved_fraction=achieved, note=note)

@@ -70,12 +70,14 @@ PyTree = Any
 # paged decode blocks (x: (B,1,d); kv leaves: (num_pages, page_size, ...))
 # ---------------------------------------------------------------------------
 
-def _paged_gqa_attn(p, xn, cfg: ModelConfig, kv, table, lengths, ps):
+def _paged_gqa_attn(p, xn, cfg: ModelConfig, kv, table, lengths, ps,
+                    use_kernel=None):
     pos = lengths[:, None]                       # rope position of new token
     q, k, v = project_qkv(p, xn, cfg, pos)
     kc = attn_lib.scatter_token(kv["k"], k[:, 0], table, lengths, ps)
     vc = attn_lib.scatter_token(kv["v"], v[:, 0], table, lengths, ps)
-    o = attn_lib.paged_decode_attention(q, kc, vc, table, lengths + 1)
+    o = attn_lib.paged_decode_attention(q, kc, vc, table, lengths + 1,
+                                        use_kernel=use_kernel)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), {"k": kc, "v": vc}
 
 
@@ -93,39 +95,44 @@ def _paged_mla_attn(p, xn, cfg: ModelConfig, kv, table, lengths, ps):
     return o, {"ckv": ckv, "krope": krope}
 
 
-def _paged_dense_block(p, x, cfg, kv, table, lengths, ps):
+def _paged_dense_block(p, x, cfg, kv, table, lengths, ps, use_kernel=None):
     xn = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.mla is not None:
         a, kv = _paged_mla_attn(p["attn"], xn, cfg, kv, table, lengths, ps)
     else:
-        a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps)
+        a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps,
+                                use_kernel)
     x = x + a
     xn = rmsnorm(p["ln2"], x, cfg.norm_eps)
     return x + mlp(p["mlp"], xn), kv
 
 
-def _paged_moe_block(p, x, cfg, kv, table, lengths, ps):
+def _paged_moe_block(p, x, cfg, kv, table, lengths, ps, use_kernel=None):
     xn = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.mla is not None:
         a, kv = _paged_mla_attn(p["attn"], xn, cfg, kv, table, lengths, ps)
     else:
-        a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps)
+        a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps,
+                                use_kernel)
     x = x + a
     xn = rmsnorm(p["ln2"], x, cfg.norm_eps)
     y, _ = moe_lib.moe_ffn(p["moe"], xn, cfg)
     return x + y, kv
 
 
-def _paged_hybrid_block(p, x, cfg, kv, table, ssm_state, lengths, ps):
+def _paged_hybrid_block(p, x, cfg, kv, table, ssm_state, lengths, ps,
+                        use_kernel=None):
     xn = rmsnorm(p["ln1"], x, cfg.norm_eps)
-    a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps)
+    a, kv = _paged_gqa_attn(p["attn"], xn, cfg, kv, table, lengths, ps,
+                            use_kernel)
     s, ssm_state = ssm_lib.ssm_decode(p["ssm"], xn, cfg, ssm_state)
     x = x + hymba_lib.fuse(p["fusion"], a, s, cfg)
     xn = rmsnorm(p["ln2"], x, cfg.norm_eps)
     return x + mlp(p["mlp"], xn), kv, ssm_state
 
 
-def _paged_hybrid_decode(params, cfg, pool, state, table, lengths, h, ps):
+def _paged_hybrid_decode(params, cfg, pool, state, table, lengths, h, ps,
+                         use_kernel=None):
     """Global layers page; SWA rings and SSM state stay per-row slabs."""
     gids = set(hymba_lib.global_layer_ids(cfg))
     kinds = ["g" if i in gids else "s" for i in range(cfg.n_layers)]
@@ -149,7 +156,8 @@ def _paged_hybrid_decode(params, cfg, pool, state, table, lengths, h, ps):
             def step(carry, xs):
                 p_l, kv_l, ssm_l = xs
                 x, kv, ssm = _paged_hybrid_block(
-                    p_l, carry, cfg, kv_l, table, ssm_l, lengths, ps)
+                    p_l, carry, cfg, kv_l, table, ssm_l, lengths, ps,
+                    use_kernel)
                 return x, (kv, ssm)
         else:
             part_kv = part(state["swa_kv"])
@@ -180,9 +188,13 @@ def _paged_hybrid_decode(params, cfg, pool, state, table, lengths, h, ps):
     return h, pool, state
 
 
-def make_paged_decode(cfg: ModelConfig, page_size: int) -> Callable:
+def make_paged_decode(cfg: ModelConfig, page_size: int, *,
+                      use_kernel: Optional[bool] = None) -> Callable:
     """decode(params, pool, state, page_table, tokens (B,1), lengths (B,))
-    -> (logits, pool, state, lengths+1)."""
+    -> (logits, pool, state, lengths+1).
+
+    ``use_kernel`` picks the paged attention path (default: the Pallas
+    kernel on TPU, the gather path elsewhere)."""
     ps = page_size
 
     def decode(params, pool, state, page_table, tokens, lengths):
@@ -190,7 +202,7 @@ def make_paged_decode(cfg: ModelConfig, page_size: int) -> Callable:
 
         if cfg.family in ("dense", "audio", "vlm"):
             body = lambda x, p, kv: _paged_dense_block(
-                p, x, cfg, kv, page_table, lengths, ps)
+                p, x, cfg, kv, page_table, lengths, ps, use_kernel)
             h, kv = E._scan_decode(params["blocks"], pool["kv"], h, lengths,
                                    body, use_scan=cfg.scan_layers)
             pool = {"kv": kv}
@@ -203,12 +215,12 @@ def make_paged_decode(cfg: ModelConfig, page_size: int) -> Callable:
             kv_d, kv_m = split(kv) if m.first_dense else (None, kv)
             if m.first_dense:
                 body_d = lambda x, p, k: _paged_dense_block(
-                    p, x, cfg, k, page_table, lengths, ps)
+                    p, x, cfg, k, page_table, lengths, ps, use_kernel)
                 h, kv_d = E._scan_decode(params["dense_blocks"], kv_d, h,
                                          lengths, body_d,
                                          use_scan=cfg.scan_layers)
             body_m = lambda x, p, k: _paged_moe_block(
-                p, x, cfg, k, page_table, lengths, ps)
+                p, x, cfg, k, page_table, lengths, ps, use_kernel)
             h, kv_m = E._scan_decode(params["moe_blocks"], kv_m, h, lengths,
                                      body_m, use_scan=cfg.scan_layers)
             joined = (jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
@@ -217,7 +229,8 @@ def make_paged_decode(cfg: ModelConfig, page_size: int) -> Callable:
 
         elif cfg.family == "hybrid":
             h, pool, state = _paged_hybrid_decode(
-                params, cfg, pool, state, page_table, lengths, h, ps)
+                params, cfg, pool, state, page_table, lengths, h, ps,
+                use_kernel)
 
         elif cfg.family == "ssm":
             h, state = E._xlstm_decode(params, cfg, state, h)
@@ -432,12 +445,14 @@ class PagedServingEngine:
     ``max_reqs`` concurrent rows; admission needs one free row plus the
     request's full page budget. Decode proceeds while new requests prefill
     into free pages between steps, and completed chains are reclaimed
-    immediately.
+    immediately. ``use_kernel`` picks the decode attention path (default:
+    the Pallas paged kernel on TPU, the gather path elsewhere).
     """
 
     def __init__(self, cfg: ModelConfig, params, *, num_pages: int = 65,
                  page_size: int = 16, max_reqs: int = 8,
-                 prompt_len: int = 64, max_len: int = 256) -> None:
+                 prompt_len: int = 64, max_len: int = 256,
+                 use_kernel: Optional[bool] = None) -> None:
         if max_len % page_size:
             raise ValueError(f"max_len={max_len} must be a multiple of "
                              f"page_size={page_size}")
@@ -465,7 +480,7 @@ class PagedServingEngine:
         self.prefill_tokens = 0    # tokens actually run through prefill
         self.shared_tokens = 0     # prompt tokens served from shared pages
 
-        _dec = make_paged_decode(cfg, page_size)
+        _dec = make_paged_decode(cfg, page_size, use_kernel=use_kernel)
 
         def _step(params, pool, state, table, tokens, lengths):
             logits, pool, state, lengths = _dec(params, pool, state, table,

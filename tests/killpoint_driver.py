@@ -45,7 +45,7 @@ def reexec_with_devices() -> int:
         f"--xla_force_host_platform_device_count={_DEVICES} "
         + env.get("XLA_FLAGS", "")).strip()
     env[_CHILD_ENV] = "1"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"           # virtual CPU devices, never a chip
     proc = subprocess.run([sys.executable, os.path.abspath(__file__)]
                           + sys.argv[1:], env=env)
     return proc.returncode
@@ -54,43 +54,42 @@ def reexec_with_devices() -> int:
 def run_scenario(steps: int, fail_at: int, ckpt_every: int,
                  ckpt_dir: str, grace_s: float = 2.5) -> dict:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.configs import base as configs
     from repro.core import Session
     from repro.distributed import sharding
     from repro.launch import train
+    from repro.launch.mesh import make_mesh
 
     assert len(jax.devices()) >= 4, jax.devices()
     cfg = configs.get("smollm-135m", smoke=True)
     shape = configs.SMOKE_SHAPE
     step_cfg = train.StepConfig()
 
-    def synth_batch(step: int) -> dict:
+    def synth_batch(step: int, shardings) -> dict:
         rng = np.random.RandomState(1000 + step)
         b, s = shape.global_batch, shape.seq_len
-        return {
-            "tokens": jnp.asarray(
-                rng.randint(0, cfg.vocab_size, (b, s)), jnp.int32),
-            "labels": jnp.asarray(
-                rng.randint(0, cfg.vocab_size, (b, s)), jnp.int32),
-        }
+        return jax.device_put({
+            "tokens": rng.randint(0, cfg.vocab_size, (b, s)).astype(np.int32),
+            "labels": rng.randint(0, cfg.vocab_size, (b, s)).astype(np.int32),
+        }, shardings)
 
-    def fresh_state(mesh):
+    def fresh_state(mesh, shardings):
         with sharding.mesh_context(mesh):
-            return train.init_state(cfg, jax.random.PRNGKey(0), step_cfg.opt)
+            state = train.init_state(cfg, jax.random.PRNGKey(0), step_cfg.opt)
+        # jit does not reshard a committed argument to its in_shardings
+        return jax.device_put(state, shardings)
 
     # -- golden: the non-failed run on the full (2, 2) mesh ------------------
-    mesh_full = jax.sharding.Mesh(
-        np.asarray(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
-    jit_full, _, _, _ = train.jit_train_step(cfg, mesh_full, step_cfg, shape,
-                                             donate=False)
+    mesh_full = make_mesh((2, 2), ("data", "model"), devices=jax.devices())
+    jit_full, st_full, b_full, _ = train.jit_train_step(
+        cfg, mesh_full, step_cfg, shape, donate=False)
     golden_losses = []
     with sharding.mesh_context(mesh_full):
-        state = fresh_state(mesh_full)
+        state = fresh_state(mesh_full, st_full)
         for i in range(steps):
-            state, metrics = jit_full(state, synth_batch(i))
+            state, metrics = jit_full(state, synth_batch(i, b_full))
             golden_losses.append(float(metrics["loss"]))
 
     # -- failure: fault preset + checkpoints; host 1 dies at fail_at ---------
@@ -111,14 +110,14 @@ def run_scenario(steps: int, fail_at: int, ckpt_every: int,
     detect_step = None
     failed = []
     with sharding.mesh_context(mesh_full):
-        state = fresh_state(mesh_full)
+        state = fresh_state(mesh_full, st_full)
         with Session(plan, clock=lambda: now[0],
                      raise_on_error=True) as session:
             session.set_checkpoint_meta(mesh=mesh_full)
             ctrl = session.fault_controller()
             for i in range(steps):
                 now[0] += 1.0
-                state, metrics = jit_full(state, synth_batch(i))
+                state, metrics = jit_full(state, synth_batch(i, b_full))
                 session.emit("train_state", i, lambda s=state: s)
                 beats = {0: 0.1}
                 if i < fail_at:
@@ -149,11 +148,12 @@ def run_scenario(steps: int, fail_at: int, ckpt_every: int,
         rm = session.remesh
         mesh_new = rm.mesh
         with sharding.mesh_context(mesh_new):
-            jit_new, _, _, _ = train.jit_train_step(cfg, mesh_new, step_cfg,
-                                                    shape, donate=False)
+            jit_new, st_new, b_new, _ = train.jit_train_step(
+                cfg, mesh_new, step_cfg, shape, donate=False)
+            state = jax.device_put(state, st_new)
             session.set_checkpoint_meta(mesh=mesh_new)
             for i in range(start + 1, steps):
-                state, metrics = jit_new(state, synth_batch(i))
+                state, metrics = jit_new(state, synth_batch(i, b_new))
                 resumed_losses[i] = float(metrics["loss"])
                 session.emit("train_state", i, lambda s=state: s)
             session.wait_idle()

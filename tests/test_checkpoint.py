@@ -180,12 +180,13 @@ def test_manifest_metadata_and_restart_counter(tmp_path):
 
 def test_elastic_restore_resharding(tmp_path):
     """Restore under a different (1-device) mesh sharding — re-placement."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     state = _state()
     mgr = CheckpointManager(CheckpointConfig(str(tmp_path),
                                              mode=InSituMode.SYNC, every=1))
     mgr.save(2, state)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shard = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     step, restored = mgr.restore(state, shardings=shard)
     w = restored["params"]["w"]

@@ -485,6 +485,7 @@ def test_widen_every_caps():
 def test_elastic_restore_requires_mesh_meta(tmp_path):
     import jax
     import jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     plan = {"streams": ["state"], "tasks": {
         "checkpoint": {"stream": "state", "preset": "checkpoint",
                        "every": 1, "placement": "sync",
@@ -500,11 +501,12 @@ def test_elastic_restore_requires_mesh_meta(tmp_path):
 def test_elastic_restore_single_device_roundtrip(tmp_path):
     import jax
     import jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     plan = {"streams": ["state"], "tasks": {
         "checkpoint": {"stream": "state", "preset": "checkpoint",
                        "every": 1, "placement": "sync",
                        "options": {"directory": str(tmp_path)}}}}
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     state = {"w": jnp.arange(8, dtype=jnp.float32)}
     with Session(plan) as s:
         s.set_checkpoint_meta(mesh=mesh)

@@ -59,7 +59,8 @@ def test_int8_quantization_error_bounded(rng):
 
 def test_int8_ring_mean_single_device_mesh():
     """n=1 ring degenerates to quantize+dequantize (shard_map on 1 device)."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("pod",))
     x = jnp.asarray(np.random.default_rng(0)
                     .standard_normal(256).astype(np.float32))
 

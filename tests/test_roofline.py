@@ -58,9 +58,10 @@ def test_async_pairs_counted_once():
 def test_report_bottleneck_and_fraction():
     rep = R.analyze(
         arch="x", shape="train_4k", mesh_desc="16x16", chips=256,
+        device_kind=R.V5E,
         cost={"flops": 1e12, "bytes accessed": 1e9}, hlo_text=HLO,
         model_flops_global=0.7 * 1e12 * 256)
-    assert rep.compute_s == pytest.approx(1e12 / R.PEAK_FLOPS_BF16)
+    assert rep.compute_s == pytest.approx(1e12 / R.peaks(R.V5E).flops_bf16)
     assert rep.bottleneck == "compute"
     assert 0.6 < rep.roofline_fraction() <= 0.71
     assert rep.useful_flops_ratio == pytest.approx(0.7)
@@ -94,6 +95,7 @@ def test_unknown_dtype_collectives_are_counted_not_dropped():
     assert ops[0].elem_bytes == 0 and ops[0].wire_bytes == 0.0
     assert ops[0].shape == (4096,) and ops[0].group_size == 4
     rpt = R.analyze(arch="a", shape="s", mesh_desc="m", chips=4,
+                    device_kind=R.V5E,
                     cost={"flops": 1e12, "bytes accessed": 1e9},
                     hlo_text=hlo, model_flops_global=1e12)
     assert "f8e8m0" in rpt.note and "lower bound" in rpt.note
@@ -104,6 +106,7 @@ def test_unknown_dtype_collectives_are_counted_not_dropped():
 
 def test_known_dtype_report_has_no_unknown_note():
     rpt = R.analyze(arch="a", shape="s", mesh_desc="m", chips=4,
+                    device_kind=R.V5E,
                     cost={"flops": 1e12, "bytes accessed": 1e9},
                     hlo_text=HLO, model_flops_global=1e12)
     assert rpt.note == ""
@@ -114,25 +117,51 @@ def test_known_dtype_report_has_no_unknown_note():
 def test_kernel_report_places_fn_on_roofline():
     import jax.numpy as jnp
 
-    from repro.roofline.kernels import RIDGE_INTENSITY, kernel_report
+    from repro.roofline.kernels import kernel_report
 
     def mm(a, b):
         return a @ b
 
     a = jnp.ones((256, 256), jnp.float32)
-    rpt = kernel_report(mm, (a, a), name="mm", measure=True)
+    rpt = kernel_report(mm, (a, a), name="mm", measure=True,
+                        device_kind=R.V5E)
     assert rpt.name == "mm"
     assert rpt.bound in ("compute", "memory")
     assert rpt.roofline_s == max(rpt.compute_s, rpt.memory_s)
-    assert rpt.ridge_intensity == RIDGE_INTENSITY
+    assert rpt.ridge_intensity == R.peaks(R.V5E).ridge_intensity
     assert rpt.measured_s is not None and rpt.measured_s > 0
     assert rpt.achieved_fraction is not None
     d = rpt.to_dict()
     assert d["bound"] == rpt.bound and "flops" in d
     # overrides drive the placement when cost_analysis is not trusted
     # (interpret-mode pallas prices the interpreter, not the kernel)
-    rpt2 = kernel_report(mm, (a, a), flops_override=1e9, bytes_override=1e6)
+    rpt2 = kernel_report(mm, (a, a), flops_override=1e9, bytes_override=1e6,
+                         device_kind=R.V5E)
     assert rpt2.flops == 1e9 and rpt2.bytes_accessed == 1e6
     assert rpt2.intensity == pytest.approx(1e3)
     assert rpt2.bound == "compute"   # 5.1us of math vs 1.2us of HBM
     assert rpt2.measured_s is None
+
+
+def test_peaks_table_v5e_row_and_source():
+    v5e = R.peaks("TPU v5 lite")         # jax's device_kind for a v5e chip
+    assert v5e.flops_bf16 == 197e12 and v5e.hbm_bw == 819e9
+    assert "TPU v5e" in v5e.source
+    assert v5e.ridge_intensity == pytest.approx(197e12 / 819e9)
+
+
+def test_unknown_device_kind_raises_instead_of_assuming_v5e():
+    import jax.numpy as jnp
+
+    from repro.roofline.kernels import kernel_report
+    with pytest.raises(KeyError, match="no published peaks"):
+        R.peaks("cpu")
+    with pytest.raises(KeyError, match="no published peaks"):
+        R.analyze(arch="a", shape="s", mesh_desc="m", chips=1,
+                  device_kind="TPU v9 imaginary", cost={"flops": 1.0},
+                  hlo_text="", model_flops_global=1.0)
+    # kernel_report defaults to the kind of the device it runs on: the
+    # CPU test backend has no row, so pricing it must fail loudly
+    a = jnp.ones((8, 8), jnp.float32)
+    with pytest.raises(KeyError, match="no published peaks"):
+        kernel_report(lambda x: x @ x, (a,), device_kind="cpu")

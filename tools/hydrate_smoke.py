@@ -104,11 +104,15 @@ def replica_main(port: int, seed: int, steps: int) -> int:
 # ---------------------------------------------------------------------------
 
 def main() -> int:
+    # a chip belongs to one process, and the bit-identical comparison needs
+    # both processes on one backend: producer and replica run on the CPU
+    os.environ["JAX_PLATFORMS"] = "cpu"
     port = _free_port()
     child = subprocess.Popen(
         [sys.executable, "-u", os.path.abspath(__file__), "--replica",
          "--port", str(port), "--seed", "0", "--steps", "3"],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=dict(os.environ))
 
     lines: list[str] = []
     listening = threading.Event()

@@ -54,7 +54,10 @@ def main() -> int:
          "--port", str(port), "--idle-timeout", "5",
          "--start-grace", "240",
          "--steer", steer, "--restore", "kv_pages"],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        # the producer (this process) may hold the chip: keep the
+        # consumer, which only restores bytes, off it
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     print(f"consumer listening on tcp://127.0.0.1:{port} "
           f"(pid {consumer.pid})")
 
