@@ -1,0 +1,8 @@
+"""The benchmark's modules import one another by name from bench/."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (str(BENCH), str(BENCH.parent / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
