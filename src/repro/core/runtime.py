@@ -78,7 +78,10 @@ the hand-off split (``handoff/dispatch``, ``handoff/materialize``,
 ``Telemetry.step_overlap_report`` and every benchmark figure read
 identically; host stages additionally get ``stage/<task>/<stage>`` spans
 for per-stage attribution, and a ``FanoutStage``'s stolen work items get
-``stage/<task>/<stage>/item`` spans on whichever worker ran them.
+``stage/<task>/<stage>/item`` spans on whichever worker ran them. The
+payload provider runs under ``insitu-provide/<task>``, each sink write
+attempt under ``sink/<task>``, and ``wait_idle`` under ``insitu/wait_idle``
+(the full list is in ``repro.core.telemetry``).
 """
 from __future__ import annotations
 
@@ -515,10 +518,11 @@ class PipelineRuntime:
         attempt = 0
         while True:
             try:
-                fault = self._sink_faults.get(task.name)
-                if fault is not None:
-                    fault(step)
-                return task.sink.write(step, payload)
+                with self.telemetry.span(f"sink/{task.name}", step=step):
+                    fault = self._sink_faults.get(task.name)
+                    if fault is not None:
+                        fault(step)
+                    return task.sink.write(step, payload)
             except TransientError as e:
                 attempt += 1
                 if attempt > task.retries:
@@ -647,7 +651,8 @@ class PipelineRuntime:
                 self.drops[task.name] += 1
             self.telemetry.count(f"staging/drop/{task.name}")
             return
-        payload = provider()
+        with self.telemetry.span(f"insitu-provide/{task.name}", step=step):
+            payload = provider()
         if task.device_stage is not None:
             with self.telemetry.span(f"insitu-device/{task.name}", step=step):
                 payload = task.device_stage(step, payload)
@@ -751,7 +756,7 @@ class PipelineRuntime:
     def wait_idle(self, timeout: float = 600.0) -> bool:
         """Block until every enqueued async item has finished."""
         deadline = time.monotonic() + timeout
-        with self._cv:
+        with self.telemetry.span("insitu/wait_idle"), self._cv:
             while self._finished < self._queued:
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:

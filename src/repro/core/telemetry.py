@@ -13,14 +13,43 @@ each thread appends to its own buffer (registered once, lock-free afterwards)
 and readers merge the buffers — a worker's ``span()`` in the hot loop never
 serializes on a global lock against the training thread.
 
-Aggregation is by name prefix:
-  step/compute          device step (dispatch->blocked-on-result)
-  handoff/dispatch      D2H copy dispatch the loop blocks on (the "send")
-  handoff/materialize   transfer drain on the consumer side (overlapped)
-  step/handoff          loop-blocking materialization (SYNC / non-pipelined)
-  insitu-sync/<task>    inline (blocking) task execution
-  insitu-async/<task>   worker-side task execution (overlapped)
-  staging/wait          producer blocked on a full ring (backpressure)
+``span()`` also opens a ``jax.profiler.TraceAnnotation`` of the same name for
+its duration, so every span shows on its own thread in any ``jax.profiler``
+trace, on the device ops' clock; with no trace being captured the
+annotation is a near no-op. ``record()`` logs a span after the fact and
+cannot reach the profiler: its two call sites (``data/wait``,
+``staging/wait``) appear in the log only.
+
+Every span the program records (loop = the thread that drives the steps;
+worker = an in-situ pool thread, or the loop for a SYNC firing):
+
+  step/compute              loop    device step (dispatch->blocked-on-result)
+  step/dispatch             loop    inside step/compute: the jitted step call
+                                    until it returns (``train_loop``)
+  step/sync                 loop    inside step/compute: the loss read that
+                                    waits for the step (``train_loop``)
+  data/batch                loop    next batch from the prefetcher plus its
+                                    ``device_put`` (``train_loop``)
+  data/wait                 loop    blocked on an empty prefetch queue (record)
+  insitu-provide/<task>     loop    the firing's payload provider
+  insitu-device/<task>      loop    the task's device stage
+  handoff/dispatch          loop    D2H copy dispatch the loop blocks on
+  step/handoff              loop    loop-blocking materialization (SYNC /
+                                    non-pipelined / sharded)
+  staging/wait              loop    producer blocked on a full ring (record)
+  insitu-sync/<task>        loop    inline (blocking) task execution
+  insitu-async/<task>       worker  worker-side task execution (overlapped)
+  handoff/materialize       worker  transfer drain on the consumer side
+  stage/<task>/<stage>      worker  one host stage of a firing
+  stage/<task>/<stage>/item worker  one fan-out item, on whichever thread ran it
+  sink/<task>               worker  one sink write attempt (a retry is a
+                                    second span with the same step)
+  insitu/wait_idle          loop    waiting for every outstanding firing
+  insitu/drain              loop    ring close and worker join
+  checkpoint/restore        caller  reading a checkpoint back
+
+Aggregation is by name prefix (``step_overlap_report``); a span nested
+inside another is named so that no aggregated prefix matches it twice.
 """
 from __future__ import annotations
 
@@ -29,7 +58,9 @@ import threading
 import time
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Iterator, Optional
+from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -74,14 +105,15 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, step: int = -1, **meta: Any) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            self._buf().append(
-                Span(name, t0, t1, threading.current_thread().name, step,
-                     dict(meta)))
+        with TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                t1 = time.perf_counter()
+                self._buf().append(
+                    Span(name, t0, t1, threading.current_thread().name, step,
+                         dict(meta)))
 
     def record(self, name: str, t0: float, t1: float, step: int = -1,
                **meta: Any) -> None:
@@ -118,43 +150,6 @@ class Telemetry:
         with self._lock:
             return dict(self._counters)
 
-    def wall(self, prefix: str = "") -> float:
-        """Wall-clock extent (union is approximated by max-end minus min-start)."""
-        ss = self.spans(prefix)
-        if not ss:
-            return 0.0
-        return max(s.t1 for s in ss) - min(s.t0 for s in ss)
-
-    def busy(self, prefix: str = "") -> float:
-        """Union of span intervals (true busy time across threads)."""
-        ss = self.spans(prefix)          # merged spans arrive t0-sorted
-        if not ss:
-            return 0.0
-        total = 0.0
-        cur0, cur1 = ss[0].t0, ss[0].t1
-        for s in ss[1:]:
-            if s.t0 > cur1:
-                total += cur1 - cur0
-                cur0, cur1 = s.t0, s.t1
-            else:
-                cur1 = max(cur1, s.t1)
-        return total + (cur1 - cur0)
-
-    def summary(self) -> dict[str, dict[str, float]]:
-        out: dict[str, dict[str, float]] = {}
-        by_name: dict[str, list[Span]] = defaultdict(list)
-        for s in self._merged():
-            by_name[s.name].append(s)
-        for name, ss in sorted(by_name.items()):
-            dts = [s.dt for s in ss]
-            out[name] = {
-                "n": float(len(dts)),
-                "total_s": sum(dts),
-                "mean_s": sum(dts) / len(dts),
-                "max_s": max(dts),
-            }
-        return out
-
     def step_overlap_report(self) -> dict[str, float]:
         """The paper's NSight question: did the device stall for in-situ work?
 
@@ -188,7 +183,3 @@ class Telemetry:
                 buf.clear()
             self._counters.clear()
 
-
-# A module-level default so simple call-sites don't need plumbing; the engine
-# and benchmarks construct their own instances for isolation.
-default = Telemetry()

@@ -411,11 +411,14 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
             mon = StragglerMonitor()
             losses, step_times = [], []
             for i in range(steps):
-                batch = jax.device_put(next(pf), b_sh)
+                with tm.span("data/batch", step=i):
+                    batch = jax.device_put(next(pf), b_sh)
                 t0 = time.perf_counter()
                 with session.step_span(i):
-                    state, metrics = jitted(state, batch)
-                    loss = float(metrics["loss"])
+                    with tm.span("step/dispatch", step=i):
+                        state, metrics = jitted(state, batch)
+                    with tm.span("step/sync", step=i):
+                        loss = float(metrics["loss"])
                 step_s = time.perf_counter() - t0
                 mon.observe(0, step_s)
                 losses.append(loss)
