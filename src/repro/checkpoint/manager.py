@@ -46,7 +46,7 @@ from typing import Any, Callable, Optional
 import jax
 
 from repro.checkpoint import serialization as ser
-from repro.core import transport
+from repro.core import codecs, transport
 from repro.core.runtime import (FanoutStage, PipelineRuntime, PipelineTask,
                                 Placement, Stage)
 from repro.core.telemetry import Telemetry
@@ -71,7 +71,7 @@ class CheckpointConfig:
     mode: Placement = Placement.ASYNC
     every: int = 100
     keep: int = 3
-    lossless: str = "zlib"
+    lossless: str = "zstd"
     lossy_eps: float = 1e-2
     lossy_moments: bool = True
     p_i: int = 2                      # workers for a manager-owned runtime
@@ -100,6 +100,11 @@ class CheckpointConfig:
             raise ValueError(
                 f"CheckpointConfig.shard_count must be >= 1, "
                 f"got {self.shard_count}")
+        if self.lossless not in codecs.available():
+            raise ValueError(
+                f"CheckpointConfig.lossless={self.lossless!r} is not "
+                f"available here (codecs: {codecs.available()}); the "
+                "default 'zstd' needs the zstandard package")
 
 
 class CheckpointManager:
@@ -173,7 +178,6 @@ class CheckpointManager:
                 "meta": meta or {}}
 
     def _codec_pool(self):
-        from repro.core import codecs
         return codecs.codec_pool() if self.cfg.chunk_parallel else None
 
     def _encode_stage(self, step: int, payload: dict) -> dict:

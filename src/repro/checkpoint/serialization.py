@@ -123,7 +123,7 @@ def state_to_host(state: PyTree) -> dict[str, np.ndarray | Compressed]:
 
 
 def encode_leaf(key: str, arr: np.ndarray | Compressed, *,
-                lossless: str = "zlib", eps: float = 1e-2,
+                lossless: str, eps: float = 1e-2,
                 lossy_policy: Optional[Callable[[str], bool]] = None,
                 bf16_keys: Optional[set] = None,
                 pool=None) -> tuple[bytes, dict]:
@@ -158,7 +158,7 @@ def encode_leaf(key: str, arr: np.ndarray | Compressed, *,
 
 
 def encode_blobs(host_state: dict[str, np.ndarray], *,
-                 lossless: str = "zlib", eps: float = 1e-2,
+                 lossless: str, eps: float = 1e-2,
                  lossy_policy: Optional[Callable[[str], bool]] = None,
                  bf16_keys: Optional[set] = None,
                  pool=None) -> dict[str, tuple[bytes, dict]]:
@@ -231,7 +231,7 @@ def write_encoded_shards(directory: str,
 
 
 def write_blobs(host_state: dict[str, np.ndarray], directory: str, *,
-                lossless: str = "zlib", eps: float = 1e-2,
+                lossless: str, eps: float = 1e-2,
                 lossy_policy: Optional[Callable[[str], bool]] = None,
                 bf16_keys: Optional[set] = None,
                 shard_count: int = 1) -> dict[str, dict]:

@@ -68,7 +68,7 @@ def _raw_bytes(dtype, shape: tuple) -> int:
         * np.dtype(jnp.dtype(dtype)).itemsize
 
 
-def frame_compressed(c: ref.Compressed, lossless: str = "zlib",
+def frame_compressed(c: ref.Compressed, lossless: str,
                      pool=None) -> tuple[bytes, LossyStats]:
     """Host lossless stage: pack a device-produced Compressed into bytes.
 
@@ -82,9 +82,9 @@ def frame_compressed(c: ref.Compressed, lossless: str = "zlib",
     shape = tuple(int(d) for d in c.shape)
     header = _lossy_header(c.dtype, c.n_elements, shape,
                            len(q_blob), len(s_blob))
-    blob = header + q_blob + s_blob
+    blob = b"".join((header, q_blob, s_blob))
     return blob, LossyStats(_raw_bytes(c.dtype, shape), len(blob),
-                            float(np.mean(q != 0)))
+                            np.count_nonzero(q) / max(q.size, 1))
 
 
 def _frame_chunked_q(chunks, lossless: str, pool=None) -> tuple[bytes, float]:
@@ -119,8 +119,8 @@ def _frame_chunked_q(chunks, lossless: str, pool=None) -> tuple[bytes, float]:
     return blob, nonzero / max(total, 1)
 
 
-def compress_tensor(x: jax.Array | np.ndarray, eps: float = 1e-2,
-                    lossless: str = "zlib",
+def compress_tensor(x: jax.Array | np.ndarray, eps: float = 1e-2, *,
+                    lossless: str,
                     measure: bool = False, pool=None,
                     stream: bool | None = None) -> tuple[bytes, LossyStats]:
     """Device lossy stage + host lossless stage for one tensor.
@@ -141,7 +141,7 @@ def compress_tensor(x: jax.Array | np.ndarray, eps: float = 1e-2,
         s_blob, _ = codecs.encode(np.asarray(scale), lossless, pool=pool)
         shape = tuple(int(d) for d in x.shape)
         header = _lossy_header(x.dtype, n, shape, len(q_blob), len(s_blob))
-        blob = header + q_blob + s_blob
+        blob = b"".join((header, q_blob, s_blob))
         return blob, LossyStats(_raw_bytes(x.dtype, shape), len(blob),
                                 float(kept))
     c = ops.spectral_compress(x, eps)                # device lossy stage
@@ -188,7 +188,7 @@ def moments_only_policy(path: tuple, leaf) -> bool:
     return any(tok in keys for tok in ("mu", "nu", "m1", "m2", "moment"))
 
 
-def compress_tree(tree: PyTree, eps: float = 1e-2, lossless: str = "zlib",
+def compress_tree(tree: PyTree, eps: float = 1e-2, *, lossless: str,
                   policy: Callable[[tuple, Any], bool] = moments_only_policy,
                   ) -> tuple[dict[str, bytes], dict[str, LossyStats | codecs.CompressionStats]]:
     """Returns ({path: framed blob}, {path: stats}). Lossless leaves use codecs."""
@@ -199,7 +199,7 @@ def compress_tree(tree: PyTree, eps: float = 1e-2, lossless: str = "zlib",
         key = jax.tree_util.keystr(path)
         arr = np.asarray(leaf)
         if policy(path, leaf):
-            blob, st = compress_tensor(leaf, eps, lossless)
+            blob, st = compress_tensor(leaf, eps, lossless=lossless)
         else:
             blob, st = codecs.encode(arr, lossless)
         blobs[key] = blob
@@ -227,7 +227,7 @@ class SpectralLossyCodec:
         self.lossless = lossless
 
     def encode(self, arr) -> bytes:
-        return compress_tensor(arr, self.eps, self.lossless)[0]
+        return compress_tensor(arr, self.eps, lossless=self.lossless)[0]
 
     def decode(self, blob: bytes) -> np.ndarray:
         return np.asarray(decompress_tensor(blob))

@@ -2,6 +2,7 @@
 import json
 import os
 import shutil
+import struct
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,9 @@ import pytest
 from repro import optim
 from repro.checkpoint import (CheckpointConfig, CheckpointManager,
                               serialization as ser)
+from repro.core import codecs, lossy
 from repro.core.insitu import InSituMode
+from repro.kernels import ref
 
 
 def _state(seed=0):
@@ -46,6 +49,122 @@ def test_roundtrip_all_modes(mode, tmp_path):
         - state["opt"]["mu"]["w"].astype(jnp.float32))))
     assert err < 0.05
     assert int(restored["step"]) == 3
+
+
+def _random_moments_state(seed=0):
+    """``_state`` with moments drawn at random, so the lossy bound bites."""
+    state = _state(seed)
+    k_mu, k_nu = jax.random.split(jax.random.PRNGKey(seed + 1))
+
+    def draw(k, tree, square):
+        leaves, treedef = jax.tree.flatten(tree)
+        ks = jax.random.split(k, len(leaves))
+        out = []
+        for kk, x in zip(ks, leaves):
+            r = jax.random.normal(kk, x.shape, jnp.float32) * 1e-3
+            out.append((r * r if square else r).astype(x.dtype))
+        return jax.tree.unflatten(treedef, out)
+
+    state["opt"] = {"mu": draw(k_mu, state["opt"]["mu"], False),
+                    "nu": draw(k_nu, state["opt"]["nu"], True)}
+    return state
+
+
+def _stored_blobs(step_dir):
+    """{leaf key: (stored blob, manifest entry)} of a v2 checkpoint."""
+    manifest = json.loads((step_dir / "manifest.json").read_text())
+    shards = {}
+    out = {}
+    for key, ent in manifest["leaves"].items():
+        if ent["file"] not in shards:
+            shards[ent["file"]] = (step_dir / ent["file"]).read_bytes()
+        data = shards[ent["file"]]
+        out[key] = (data[ent["offset"]:ent["offset"] + ent["bytes"]], ent)
+    return out
+
+
+def _frame_codec_ids(blob):
+    """Codec id of every lossless frame in a stored leaf blob: one for a
+    lossless leaf, two (coefficients, scales) inside a lossy leaf."""
+    if blob[:4] == lossy.LOSSY_MAGIC:
+        off = 5 + blob[4]
+        _, ndim = struct.unpack_from("<qB", blob, off)
+        off += 9 + 8 * ndim
+        qlen, _ = struct.unpack_from("<qq", blob, off)
+        off += 16
+        return [blob[off + 5], blob[off + qlen + 5]]
+    assert blob[:4] == codecs.MAGIC
+    return [blob[5]]
+
+
+def _max_rel_l2(restored, state):
+    """Worst relative-L2 gap over the moment leaves."""
+    worst = 0.0
+    for part in ("mu", "nu"):
+        for a, b in zip(jax.tree.leaves(restored["opt"][part]),
+                        jax.tree.leaves(state["opt"][part])):
+            worst = max(worst, ref.rel_l2_error(b.astype(jnp.float32),
+                                                a.astype(jnp.float32)))
+    return worst
+
+
+# the moments restore into bf16: its rounding (unit roundoff 2**-9 of the
+# decoded value) adds at most 2**-9 * (1 + bound) <= 2**-8 to the codec's bound
+_BF16_RESTORE = 2.0 ** -8
+
+
+@pytest.mark.parametrize("mode", list(InSituMode))
+def test_default_codec_is_zstd_in_every_mode(mode, tmp_path):
+    """The default config codes every stored frame with zstd (codec id 6);
+    lossless leaves restore bit-exact, lossy ones within the stated bound."""
+    state = _random_moments_state()
+    mgr = CheckpointManager(CheckpointConfig(str(tmp_path), mode=mode,
+                                             every=1))
+    assert mgr.cfg.lossless == "zstd"
+    mgr.save(5, state)
+    mgr.wait_idle()
+    mgr.finish()
+    blobs = _stored_blobs(tmp_path / "step_000000005")
+    assert sum(ent["lossy"] for _, ent in blobs.values()) == 4
+    for key, (blob, _) in blobs.items():
+        assert set(_frame_codec_ids(blob)) == {6}, key
+    _, restored = mgr.restore(state)
+    for a, b in zip(jax.tree.leaves(restored["params"]),
+                    jax.tree.leaves(state["params"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(restored["step"]) == 3
+    assert _max_rel_l2(restored, state) <= (ref.error_bound(1e-2)
+                                            + _BF16_RESTORE)
+
+
+def test_zlib_checkpoint_restores_under_zstd_default(tmp_path):
+    """A v2 checkpoint written with zlib (codec id 1) restores unchanged
+    through a manager on the zstd default: restore reads each frame's id."""
+    state = _random_moments_state(3)
+    old = CheckpointManager(CheckpointConfig(str(tmp_path), lossless="zlib",
+                                             mode=InSituMode.SYNC, every=1))
+    old.save(9, state)
+    _, want = old.restore(state)
+    old.finish()
+    blobs = _stored_blobs(tmp_path / "step_000000009")
+    assert all(set(_frame_codec_ids(b)) == {1} for b, _ in blobs.values())
+
+    new = CheckpointManager(CheckpointConfig(str(tmp_path), every=1))
+    step, got = new.restore(state)
+    new.finish()
+    assert step == 9
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(got["params"]["w"]),
+                                  np.asarray(state["params"]["w"]))
+
+
+def test_default_codec_missing_is_a_clear_error(tmp_path, monkeypatch):
+    """Without zstandard the default must refuse, not write zlib silently."""
+    monkeypatch.delitem(codecs._COMPRESSORS, "zstd")
+    with pytest.raises(ValueError, match="zstandard"):
+        CheckpointConfig(str(tmp_path))
+    assert CheckpointConfig(str(tmp_path), lossless="zlib").lossless == "zlib"
 
 
 def test_v2_layout_single_shard_and_offset_table(tmp_path):

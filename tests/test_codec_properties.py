@@ -259,12 +259,15 @@ def test_streamed_chunked_lossy_frame_byte_identical(n):
 
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    blob_plain, st_plain = lossy.compress_tensor(x, 1e-2, stream=False)
-    blob_stream, st_stream = lossy.compress_tensor(x, 1e-2, stream=True)
+    blob_plain, st_plain = lossy.compress_tensor(x, 1e-2, lossless="zstd",
+                                                   stream=False)
+    blob_stream, st_stream = lossy.compress_tensor(x, 1e-2, lossless="zstd",
+                                                     stream=True)
     assert blob_stream == blob_plain
     assert st_stream == st_plain
     pool = codecs.codec_pool()
-    blob_pool, _ = lossy.compress_tensor(x, 1e-2, stream=True, pool=pool)
+    blob_pool, _ = lossy.compress_tensor(x, 1e-2, lossless="zstd",
+                                         stream=True, pool=pool)
     assert blob_pool == blob_plain
     rt = np.asarray(lossy.decompress_tensor(blob_stream))
     rt_plain = np.asarray(lossy.decompress_tensor(blob_plain))

@@ -1,6 +1,7 @@
 """Lossless codec layer: framing, roundtrips, Table II-style ratios."""
 import numpy as np
 import pytest
+import zstandard
 from _hyp import given, settings, st   # optional-hypothesis shim
 
 from repro.core import codecs
@@ -137,3 +138,104 @@ def test_unsupported_version_rejected(rng):
     bad = blob[:4] + bytes([99]) + blob[5:]
     with pytest.raises(ValueError, match="version"):
         codecs.decode(bad)
+
+
+# -- zstd: the checkpoint's lossless codec ------------------------------------
+
+def _fresh_zstd_frame(arr, chunk_bytes):
+    """The frame a fresh ZstdCompressor per chunk gives: what every earlier
+    "zstd" frame holds, so reusing one compressor per thread must match."""
+    views = codecs._chunk_views(np.ascontiguousarray(arr), chunk_bytes)
+    payloads = [zstandard.ZstdCompressor(level=3).compress(bytes(v))
+                for v in views]
+    return codecs.assemble_frame("zstd", arr.dtype, arr.shape, arr.nbytes,
+                                 chunk_bytes, payloads)
+
+
+def test_zstd_frames_match_a_fresh_compressor(rng):
+    arr = (rng.standard_normal(300_000) * 50).astype(np.int16)
+    for chunk_bytes in (1 << 14, codecs.DEFAULT_CHUNK):
+        blob, _ = codecs.encode(arr, "zstd", chunk_bytes=chunk_bytes)
+        assert blob[5] == 6                     # codec id unchanged
+        assert blob == _fresh_zstd_frame(arr, chunk_bytes)
+
+
+def test_zstd_concurrent_encodes_match_serial(rng):
+    """Many arrays coded at once on the codec pool's threads give the
+    frames a serial encode gives, and decode back concurrently: each
+    thread's own (de)compressor is never shared."""
+    import sys
+    arrays = [(rng.standard_normal(20_000 + 997 * i) * 10).astype(np.float32)
+              for i in range(48)]
+    serial = [codecs.encode(a, "zstd", chunk_bytes=1 << 13)[0]
+              for a in arrays]
+    pool = codecs.codec_pool()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        futs = [pool.submit(codecs.encode, a, "zstd", chunk_bytes=1 << 13)
+                for a in arrays]
+        concurrent = [f.result(timeout=60)[0] for f in futs]
+        back = [f.result(timeout=60)
+                for f in [pool.submit(codecs.decode, b) for b in concurrent]]
+    finally:
+        sys.setswitchinterval(old)
+    assert concurrent == serial
+    for a, b in zip(arrays, back):
+        np.testing.assert_array_equal(a, b)
+    chunked, _ = codecs.encode(arrays[-1], "zstd", chunk_bytes=1 << 13,
+                               pool=pool)
+    assert chunked == serial[-1]
+
+
+def _bf16_weights(seed):
+    import ml_dtypes
+    r = np.random.default_rng(seed)
+    w = (r.standard_normal((512, 1024), dtype=np.float32) * 0.02)
+    return w.astype(ml_dtypes.bfloat16).view(np.uint16)
+
+
+def _coefficients(seed, square):
+    import jax.numpy as jnp
+    from repro.kernels import ref
+    r = np.random.default_rng(seed)
+    g = r.standard_normal((512, 1024), dtype=np.float32) * 1e-3
+    return np.asarray(ref.compress(jnp.asarray(g * g if square else g),
+                                   1e-2).q)
+
+
+@pytest.mark.parametrize("leaf", ["bf16_weights", "q_first_moment",
+                                  "q_second_moment"])
+def test_zstd_stores_no_more_than_zlib(leaf):
+    """The checkpoint's leaf classes at a fixed seed: the zstd default
+    writes no more bytes than zlib-6 did."""
+    arr = {"bf16_weights": lambda: _bf16_weights(1234),
+           "q_first_moment": lambda: _coefficients(1234, False),
+           "q_second_moment": lambda: _coefficients(1234, True)}[leaf]()
+    zstd_blob, _ = codecs.encode(arr, "zstd")
+    zlib_blob, _ = codecs.encode(arr, "zlib")
+    assert len(zstd_blob) <= len(zlib_blob)
+    np.testing.assert_array_equal(codecs.decode(zstd_blob), arr)
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_lossy_blob_is_header_and_both_frames(stream):
+    """The lossy frame, joined in one pass, holds the bytes of header +
+    coefficient frame + scale frame; the kept share is the share of
+    nonzero coefficients."""
+    import jax.numpy as jnp
+    from repro.core import lossy
+    from repro.kernels import ops
+    r = np.random.default_rng(7)
+    x = jnp.asarray(r.standard_normal(3 * codecs.DEFAULT_CHUNK // 2,
+                                      dtype=np.float32) * 1e-3)
+    blob, st = lossy.compress_tensor(x, 1e-2, lossless="zstd", stream=stream)
+    c = ops.spectral_compress(x, 1e-2)
+    q, scale = np.asarray(c.q), np.asarray(c.scale)
+    q_blob, _ = codecs.encode(q, "zstd")
+    s_blob, _ = codecs.encode(scale, "zstd")
+    header = lossy._lossy_header(c.dtype, c.n_elements, tuple(c.shape),
+                                 len(q_blob), len(s_blob))
+    assert blob == header + q_blob + s_blob
+    assert st.stored_bytes == len(blob)
+    assert st.kept_fraction == float(np.mean(q != 0))

@@ -20,14 +20,14 @@ def _tree(rng):
 
 def test_policy_selects_moments_only(rng):
     tree = _tree(rng)
-    blobs, stats = lossy.compress_tree(tree, eps=1e-2)
+    blobs, stats = lossy.compress_tree(tree, eps=1e-2, lossless="zstd")
     lossy_keys = {k for k, b in blobs.items() if b[:4] == lossy.LOSSY_MAGIC}
     assert lossy_keys == {"['opt']['mu']", "['opt']['nu']"}
 
 
 def test_restore_tree_structure_and_errors(rng):
     tree = _tree(rng)
-    blobs, _ = lossy.compress_tree(tree, eps=1e-2)
+    blobs, _ = lossy.compress_tree(tree, eps=1e-2, lossless="zstd")
     rt = lossy.restore_tree(tree, blobs)
     assert jax.tree_util.tree_structure(rt) == \
         jax.tree_util.tree_structure(tree)
@@ -39,7 +39,7 @@ def test_restore_tree_structure_and_errors(rng):
 
 def test_frame_roundtrip_bf16():
     x = jnp.asarray(np.linspace(-2, 2, 777), dtype=jnp.bfloat16)
-    blob, st_ = lossy.compress_tensor(x, eps=1e-2)
+    blob, st_ = lossy.compress_tensor(x, eps=1e-2, lossless="zstd")
     y = lossy.decompress_tensor(blob)
     assert y.dtype == jnp.bfloat16 and y.shape == x.shape
     err = float(jnp.max(jnp.abs((y - x).astype(jnp.float32))))
@@ -48,7 +48,8 @@ def test_frame_roundtrip_bf16():
 
 def test_measure_flag_reports_error(rng):
     x = jnp.asarray(rng.standard_normal(2048).astype(np.float32))
-    _, st_ = lossy.compress_tensor(x, eps=1e-1, measure=True)
+    _, st_ = lossy.compress_tensor(x, eps=1e-1, lossless="zstd",
+                                   measure=True)
     assert st_.rel_l2_error is not None
     assert st_.rel_l2_error <= lossy.error_bound(1e-1) + 1e-5
 
