@@ -159,9 +159,13 @@ class CheckpointManager:
         return default_lossy_policy if self.cfg.lossy_moments else None
 
     def _device_lossy(self, step: int, payload: tuple) -> tuple:
-        """Device stage (HYBRID): spectral-lossy the moment leaves in-place."""
+        """Device stage (HYBRID): spectral-lossy the moment leaves in-place.
+        Counts its fused launches (``checkpoint/compress_launches``)."""
         from repro.kernels import ops as kops
         state, meta = payload
+        self.telemetry.count("checkpoint/compress_launches",
+                             kops.compress_launches(state,
+                                                    default_lossy_policy))
         state = kops.spectral_compress_tree(state, self.cfg.lossy_eps,
                                             default_lossy_policy)
         return state, meta

@@ -16,28 +16,45 @@ def _round_up(x: int, m: int) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                # routed experts of a layer (router outputs)
     top_k: int
     d_expert: int                 # per-expert ffn hidden
     n_shared_experts: int = 0
     first_dense: int = 0          # leading dense layers (deepseek: 3)
     dense_d_ff: int = 0           # d_ff of those dense layers
-    capacity_factor: float = 1.25
+    # None: dropless routing over the held experts (grouped matmul); a
+    # number: GShard capacity C = ceil(chunk*k/E * cf), overflow dropped
+    capacity_factor: Optional[float] = 1.25
     token_chunk: int = 32768      # GShard dispatch group: ~2k tokens/device x 16 DP
     router_aux_weight: float = 0.001
     router_z_weight: float = 0.0001
-    # hillclimb lever: split each chunk into n_groups DP-local dispatch
-    # groups (groups sharded over the dp axes) — the dispatch/combine
-    # einsums then contract DP-locally and only the (g,e,c,d)->expert
-    # transition crosses shards, instead of all-reducing the dispatched
-    # tensor over 'data'. See EXPERIMENTS.md §Perf.
+    # split each capacity chunk into n_groups DP-local dispatch groups
+    # (groups sharded over the dp axes): the dispatch/combine einsums then
+    # contract DP-locally and only the (g,e,c,d)->expert transition
+    # crosses shards
     grouped_dispatch: bool = False
     n_groups: int = 16
+    # the dropless path routes as DeepSeek-V3 and Moonlight publish it
+    # (``scoring_func`` sigmoid, ``topk_method`` noaux_tc,
+    # ``norm_topk_prob`` true), weights times ``routed_scaling_factor``
+    routed_scale: float = 1.0
+    # auxiliary-loss-free balancing (arXiv:2412.19437): a per-expert bias
+    # added to the scores for selection only, moved by +-bias_update_rate
+    # after each step against each expert's load; 0 keeps no bias
+    bias_update_rate: float = 0.0
+    seq_aux_weight: float = 0.0   # alpha of the sequence-wise balance loss
+    # routed experts this chip holds, experts [0, n_held) (0: all): one
+    # chip's share of an expert-parallel deployment
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora: int = 1536
+    q_lora: Optional[int] = 1536  # None: full-rank query projection
     kv_lora: int = 512
     qk_nope: int = 128
     qk_rope: int = 64
@@ -122,15 +139,35 @@ class ModelConfig:
         return P.param_count(transformer.param_spec(self))
 
     def n_active_params(self) -> int:
-        """Params touched per token (MoE: shared + top_k experts only)."""
+        """Params touched per token (MoE: shared experts plus, of the held
+        routed experts, the top_k x held / n_experts a token uses on
+        average)."""
         total = self.n_params()
         if self.moe is None:
             return total
         m = self.moe
         per_expert = 3 * self.d_model * m.d_expert
         n_moe_layers = self.n_layers - m.first_dense
-        inactive = (m.n_experts - m.top_k) * per_expert * n_moe_layers
-        return total - inactive
+        inactive = (m.held - m.top_k * m.held / m.n_experts) \
+            * per_expert * n_moe_layers
+        return int(round(total - inactive))
+
+
+def chip_share(cfg: ModelConfig, *, n_layers: int, n_held: int,
+               vocab_size: int) -> ModelConfig:
+    """One chip's share of an expert-parallel deployment of ``cfg``: its
+    first ``n_layers`` layers (the rest lie on further pipeline stages),
+    ``n_held`` of each MoE layer's routed experts (the router keeps all
+    its outputs), and a slice of ``vocab_size`` rows of the vocabulary."""
+    m = cfg.moe
+    if m is None or not m.first_dense < n_layers <= cfg.n_layers:
+        raise ValueError(f"{cfg.name}: no MoE layer in {n_layers} layers")
+    if not 0 < n_held <= m.n_experts or vocab_size > cfg.vocab_size:
+        raise ValueError(f"{cfg.name}: a share holds 1..{m.n_experts} "
+                         f"experts and at most {cfg.vocab_size} ids")
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}-share", n_layers=n_layers,
+        vocab_size=vocab_size, moe=dataclasses.replace(m, n_held=n_held))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,7 +195,7 @@ ARCH_IDS: Sequence[str] = (
     "qwen1.5-110b",
     "musicgen-medium",
     "deepseek-v3-671b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "internvl2-26b",
     "hymba-1.5b",
     "xlstm-1.3b",
@@ -171,7 +208,7 @@ _MODULES = {
     "qwen1.5-110b": "qwen1_5_110b",
     "musicgen-medium": "musicgen_medium",
     "deepseek-v3-671b": "deepseek_v3_671b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "internvl2-26b": "internvl2_26b",
     "hymba-1.5b": "hymba_1_5b",
     "xlstm-1.3b": "xlstm_1_3b",

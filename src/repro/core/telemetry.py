@@ -50,6 +50,15 @@ worker = an in-situ pool thread, or the loop for a SYNC firing):
 
 Aggregation is by name prefix (``step_overlap_report``); a span nested
 inside another is named so that no aggregated prefix matches it twice.
+
+Counters (``count``, summed over the run) besides the sink and staging
+ones the runtime keeps:
+
+  moe/routed_pairs              (token, slot) pairs the held experts computed
+  moe/max_load_ratio            per step: largest held-expert load over the
+                                balanced load T·k / n_experts
+  moe/max_abs_bias              per step: largest |router correction bias|
+  checkpoint/compress_launches  fused device compress launches (per save)
 """
 from __future__ import annotations
 

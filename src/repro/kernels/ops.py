@@ -220,16 +220,13 @@ def _compress_tree_packed(blocks: tuple, eps: float, interpret: bool,
     one per shape.
     """
     counts = [b.shape[0] for b in blocks]
-    packed = jnp.concatenate(blocks, 0) if len(blocks) > 1 else blocks[0]
     if interpret:
-        # off-TPU: packed pure-jnp oracle (XLA compiles the unrolled
-        # per-leaf selection into the same single program).
-        y = ref.dct_blocks(packed)
+        # off-TPU: the pure-jnp oracle leaf by leaf, in one program (a DCT
+        # over the packed buffer could round a leaf differently with the
+        # company it is packed in)
         qs, ss = [], []
-        off = 0
-        for c in counts:
-            yb = y[off:off + c]
-            off += c
+        for xb in blocks:
+            yb = ref.dct_blocks(xb)
             _, energies = ref.energy_histogram(yb)
             t = ref.threshold_from_histogram(energies, eps)
             q, s = ref.quantize_blocks(yb, t)
@@ -243,6 +240,7 @@ def _compress_tree_packed(blocks: tuple, eps: float, interpret: bool,
     # per-tile histograms segment-sum exactly to the per-leaf histograms
     # the per-leaf kernels would have produced.
     import numpy as _np
+    packed = jnp.concatenate(blocks, 0) if len(blocks) > 1 else blocks[0]
     y, _, eng_t = K.dct_hist_coarse_tiled(packed, interpret=False,
                                           tile=hist_tile)
     tile_seg = jnp.asarray(_np.repeat(_np.arange(len(counts)),
@@ -267,23 +265,59 @@ def _compress_tree_packed(blocks: tuple, eps: float, interpret: bool,
     return tuple(qs), tuple(ss)
 
 
+# The largest f32 block buffer (selected leaves blockized, each padded to its
+# power-of-two bucket) that one fused compress launch packs. A launch's
+# working set is a few times this (the packed copy, the f32 DCT output), so
+# a larger tree is compressed in several launches, each under the budget.
+# smollm-135m's moments (1.08 GB of f32 blocks, 1.41 GB in buckets) still
+# go in one launch.
+COMPRESS_LAUNCH_BYTES = 3 << 29          # 1.5 GiB
+
+
+def _leaf_rows(leaf) -> tuple[int, int]:
+    """(kept rows, bucketed rows) of one leaf's f32 block buffer."""
+    rows = -(-int(leaf.size) // BLOCK)
+    real = rows + ((-rows) % K.HIST_TILE)
+    return real, _bucket_rows(real)
+
+
+def _launch_groups(buckets: list) -> list:
+    """Consecutive runs of the selected leaves (indices into ``buckets``,
+    their bucketed row counts) whose f32 block buffers stay under
+    ``COMPRESS_LAUNCH_BYTES``; a leaf over the budget goes alone."""
+    groups, cur, cur_bytes = [], [], 0
+    for j, rows in enumerate(buckets):
+        nbytes = rows * BLOCK * 4
+        if cur and cur_bytes + nbytes > COMPRESS_LAUNCH_BYTES:
+            groups.append(cur)
+            cur, cur_bytes = [], 0
+        cur.append(j)
+        cur_bytes += nbytes
+    return groups + [cur] if cur else groups
+
+
 def _packed_tree_args(flat, selected: list, eps: float):
-    """Blocks and static arguments of the fused tree dispatch: every
-    selected leaf blockized and padded to its pow2 bucket, plus the tiles.
-    Returns ``(args, keep_rows)``; ``args`` feed ``_compress_tree_packed``.
-    """
-    blocks, keep_rows = [], []
-    for i in selected:
-        xb, _ = ref.blockize(flat[i][1])
-        real = xb.shape[0] + ((-xb.shape[0]) % K.HIST_TILE)
-        keep_rows.append(real)
-        blocks.append(_pad_to_rows(xb, _bucket_rows(real)))
-    hist_tile, quant_tile = _tiles_for(max(b.shape[0] for b in blocks))
+    """The fused launches that compress the selected leaves: for each, its
+    leaves' positions in ``selected``, kept rows, and the arguments of
+    ``_compress_tree_packed`` built by ``make_args()`` (blocks, eps and
+    tiles). Blocks are built only when a launch's arguments are, so no
+    more than one launch's f32 buffer is made at a time. Tiles are chosen
+    once for the whole tree, so grouping changes no leaf's result."""
+    sizes = [_leaf_rows(flat[i][1]) for i in selected]
+    buckets = [b for _, b in sizes]
+    hist_tile, quant_tile = _tiles_for(max(buckets))
     # tiles must never straddle leaves: clamp to the smallest bucket
     # (both are powers of two, so the smaller divides every bucket).
-    hist_tile = min(hist_tile, min(b.shape[0] for b in blocks))
-    args = (tuple(blocks), float(eps), _interpret(), hist_tile, quant_tile)
-    return args, keep_rows
+    hist_tile = min(hist_tile, min(buckets))
+    launches = []
+    for group in _launch_groups(buckets):
+        def make_args(group=group):
+            blocks = tuple(
+                _pad_to_rows(ref.blockize(flat[selected[j]][1])[0], buckets[j])
+                for j in group)
+            return (blocks, float(eps), _interpret(), hist_tile, quant_tile)
+        launches.append((group, [sizes[j][0] for j in group], make_args))
+    return launches
 
 
 def _selected_leaves(state, policy):
@@ -302,40 +336,53 @@ def spectral_compress_tree(state, eps: float = 1e-2,
     Returns the same tree structure with ``Compressed`` leaves where the
     policy fired — the hand-off then ships int8 coefficients + scales.
 
-    ``fused`` (default) packs all selected leaves into one flat blocked
-    buffer and compresses the whole tree in a single fused dispatch
-    (bit-identical to the per-leaf path, which ``fused=False`` preserves
-    for comparison). Each leaf's block count is padded up to the next
-    power of two before the fused call, so the jit trace cache buckets
-    elastic-mesh shape drift instead of re-tracing per tree shape; the
-    zero pad blocks carry no energy (thresholds are unchanged) and are
-    sliced off the result.
+    ``fused`` (default) packs the selected leaves into flat blocked buffers
+    and compresses each in a single fused dispatch (bit-identical to the
+    per-leaf path, which ``fused=False`` preserves for comparison): one
+    dispatch for the whole tree while its f32 blocks fit
+    ``COMPRESS_LAUNCH_BYTES``, else one per consecutive group of leaves
+    under it. Each leaf's block count is padded up to the next power of
+    two before the fused call, so the jit trace cache buckets elastic-mesh
+    shape drift instead of re-tracing per tree shape; the zero pad blocks
+    carry no energy (thresholds are unchanged) and are sliced off the
+    result.
     """
     flat, treedef, selected = _selected_leaves(state, policy)
     new_leaves = [leaf for _, leaf in flat]
     if fused and len(selected) > 1:
-        args, keep_rows = _packed_tree_args(flat, selected, eps)
-        qs, scales = _compress_tree_packed(*args)
-        for i, q, scale, real in zip(selected, qs, scales, keep_rows):
-            leaf = flat[i][1]
-            new_leaves[i] = Compressed(q[:real], scale[:real],
-                                       int(leaf.size),
-                                       tuple(leaf.shape), leaf.dtype)
+        for group, keep_rows, make_args in _packed_tree_args(
+                flat, selected, eps):
+            qs, scales = _compress_tree_packed(*make_args())
+            for j, q, scale, real in zip(group, qs, scales, keep_rows):
+                leaf = flat[selected[j]][1]
+                new_leaves[selected[j]] = Compressed(
+                    q[:real], scale[:real], int(leaf.size),
+                    tuple(leaf.shape), leaf.dtype)
     else:
         for i in selected:
             new_leaves[i] = spectral_compress(flat[i][1], eps)
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 
+def compress_launches(state, policy=None) -> int:
+    """How many fused launches ``spectral_compress_tree`` makes for
+    ``state`` (from the selected leaves' shapes alone)."""
+    flat, _, selected = _selected_leaves(state, policy)
+    if len(selected) < 2:
+        return len(selected)
+    return len(_launch_groups([_leaf_rows(flat[i][1])[1] for i in selected]))
+
+
 def lower_compress_tree(state, eps: float = 1e-2, policy=None):
-    """The fused dispatch ``spectral_compress_tree`` runs for ``state``,
-    lowered (``.compile().as_text()`` shows whether the Pallas kernels or
-    the jnp oracle are in it)."""
+    """The first fused dispatch ``spectral_compress_tree`` runs for
+    ``state`` (the only one while the tree fits one launch), lowered
+    (``.compile().as_text()`` shows whether the Pallas kernels or the jnp
+    oracle are in it)."""
     flat, _, selected = _selected_leaves(state, policy)
     if len(selected) < 2:
         raise ValueError("the fused dispatch needs >= 2 selected leaves")
-    args, _ = _packed_tree_args(flat, selected, eps)
-    return _compress_tree_packed.lower(*args)
+    _, _, make_args = _packed_tree_args(flat, selected, eps)[0]
+    return _compress_tree_packed.lower(*make_args())
 
 
 def packed_tree_cache_size() -> int:

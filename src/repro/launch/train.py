@@ -36,6 +36,7 @@ from repro.configs import base as configs
 from repro.distributed import sharding
 from repro.launch import mesh as mesh_lib
 from repro.launch.compile_cache import enable_compile_cache
+from repro.models import moe as moe_lib
 from repro.models import params as P_lib
 from repro.models import transformer
 from repro.optim import grad_compress
@@ -157,9 +158,11 @@ def make_train_step(cfg: configs.ModelConfig, mesh: Mesh,
                                 extra_model=sharding.batch_over_model(rules))
 
     def local_grads(params, batch, bspec_):
-        loss_fn = lambda p, b: transformer.train_loss(p, cfg, b, bspec=bspec_)
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        return loss, grads
+        loss_fn = lambda p, b: transformer.train_loss_and_stats(
+            p, cfg, b, bspec=bspec_)
+        (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch)
+        return loss, grads, stats
 
     def _pod_ring_grads(params, batch, state):
         """Compressed cross-pod gradient path (manual over 'pod' only).
@@ -246,10 +249,11 @@ def make_train_step(cfg: configs.ModelConfig, mesh: Mesh,
 
     def train_step(state, batch):
         params = state["params"]
+        stats = {}
         if use_pod_ring:
             loss, grads, new_ef = _pod_ring_grads(params, batch, state)
         else:
-            loss, grads = local_grads(params, batch, bspec)
+            loss, grads, stats = local_grads(params, batch, bspec)
             new_ef = state.get("ef")
 
         lr = optim.schedules.warmup_cosine(
@@ -259,6 +263,10 @@ def make_train_step(cfg: configs.ModelConfig, mesh: Mesh,
                                   state.get("master"))
         new_params, new_ostate = optim.update(grads, ostate, params,
                                               step_cfg.opt, lr=lr)
+        moe_counters = None
+        if "load" in stats:
+            new_params, moe_counters = _moe_step(cfg, params, new_params,
+                                                 stats)
         new_state = dict(state)
         new_state.update(params=new_params, mu=new_ostate.mu,
                          nu=new_ostate.nu, count=new_ostate.count)
@@ -269,9 +277,31 @@ def make_train_step(cfg: configs.ModelConfig, mesh: Mesh,
         metrics = {"loss": loss.astype(jnp.float32),
                    "grad_norm": optim.adamw.global_norm(grads),
                    "lr": lr}
+        if moe_counters is not None:
+            metrics["moe"] = moe_counters
         return new_state, metrics
 
     return train_step
+
+
+def _moe_step(cfg: configs.ModelConfig, params, new_params, stats):
+    """The router bias's own update and the step's routing counters.
+
+    The correction bias takes no gradient (it only selects) and no weight
+    decay: whatever the optimizer made of it is replaced by the old bias
+    moved against each expert's load. Counters: (token, slot) pairs the
+    held experts computed, the largest held-expert load over the balanced
+    load, and the largest |bias| after the update."""
+    counters = {"routed_pairs": jnp.sum(stats["routed"]),
+                "max_load_ratio": jnp.max(stats["max_load"])}
+    if cfg.moe.bias_update_rate:
+        moe_p = new_params["moe_blocks"]["moe"]
+        bias = moe_lib.update_bias(params["moe_blocks"]["moe"]["e_bias"],
+                                   stats["load"], cfg.moe.bias_update_rate)
+        new_params = dict(new_params, moe_blocks=dict(
+            new_params["moe_blocks"], moe=dict(moe_p, e_bias=bias)))
+        counters["max_abs_bias"] = jnp.max(jnp.abs(bias))
+    return new_params, counters
 
 
 def jit_train_step(cfg, mesh, step_cfg: StepConfig, shape, *, rules=None,
@@ -339,7 +369,7 @@ def default_train_plan(*, insitu_mode: str = "async",
     return plan
 
 
-def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
+def train_loop(arch, *, steps: int = 50, smoke: bool = True,
                shape: Optional[configs.ShapeConfig] = None,
                insitu_mode: str = "async", ckpt_dir: Optional[str] = None,
                ckpt_every: int = 20, seed: int = 0,
@@ -362,15 +392,19 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
     (e.g. to grab a task's transport sink for a network-fault drill).
     ``shape`` sets sequence length and global batch (default: the smoke
     shape, or the production-mesh ``train_4k`` when ``smoke=False``).
-    Returns the per-step losses and host-clock step times (each ends in
-    the loss's device-to-host read), the final state, and the session's
-    telemetry and reports.
+    ``arch`` is a registry id or a ``ModelConfig`` (such as a
+    ``configs.chip_share`` of one). Returns the per-step losses and
+    host-clock step times (each ends in the loss's device-to-host read),
+    the final state, and the session's telemetry and reports. A MoE step's
+    routing counters ride the same read and add to the telemetry's
+    counters (``moe/<name>``, summed over steps).
     """
     from repro.core import InSituPlan, Session, Telemetry
     from repro.data.pipeline import Prefetcher, batch_spec_for
     from repro.distributed.fault import StragglerMonitor
 
-    cfg = configs.get(arch, smoke=smoke)
+    cfg = (arch if isinstance(arch, configs.ModelConfig)
+           else configs.get(arch, smoke=smoke))
     if shape is None:
         shape = configs.SMOKE_SHAPE if smoke else configs.SHAPES["train_4k"]
     mesh = mesh_lib.make_mesh((1, 1), ("data", "model"))
@@ -418,7 +452,11 @@ def train_loop(arch: str, *, steps: int = 50, smoke: bool = True,
                     with tm.span("step/dispatch", step=i):
                         state, metrics = jitted(state, batch)
                     with tm.span("step/sync", step=i):
-                        loss = float(metrics["loss"])
+                        loss, moe = jax.device_get(
+                            (metrics["loss"], metrics.get("moe")))
+                        loss = float(loss)
+                for name, value in (moe or {}).items():
+                    tm.count(f"moe/{name}", float(value))
                 step_s = time.perf_counter() - t0
                 mon.observe(0, step_s)
                 losses.append(loss)

@@ -23,6 +23,16 @@ import jax.numpy as jnp
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
+# A training step keeps, for the backward of each attention call, every f32
+# score block its forward made: ~B·Hq·Sq·Sk/2 floats under a causal mask.
+# A memory rule: past an eighth of a 16 GB chip in one call, the backward
+# recomputes each block (``jax.checkpoint`` on the kv-chunk body) instead of
+# keeping it. Recomputing has not been slower at any size measured; the kept
+# path goes, and the recompute becomes unconditional, once its effect on the
+# checkpointed training cells can be judged (ROADMAP.md, queue 1, the
+# attention item).
+SAVED_SCORES_BYTES = 2 << 30
+
 
 def _grouped(q: jax.Array, n_kv: int) -> jax.Array:
     """(B,S,Hq,D) -> (B,S,N,G,D) with N=kv heads, G=Hq//N."""
@@ -59,6 +69,7 @@ def flash_attention(
     g = hq // n_kv
     out = []
     n_q_chunks = sq // q_chunk
+    remat = 4 * b * hq * sq * sk // (2 if causal else 1) > SAVED_SCORES_BYTES
     for i in range(n_q_chunks):
         qs = i * q_chunk                       # chunk start (relative)
         q_abs = q_offset + qs                  # absolute start
@@ -100,6 +111,7 @@ def flash_attention(
             acc_new = acc * corr[..., None].astype(acc.dtype) + pv.astype(jnp.float32)
             return (m_new, l_new, acc_new), None
 
+        step = jax.checkpoint(body, prevent_cse=False) if remat else body
         m0 = jnp.full((b, n_kv, g, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, n_kv, g, q_chunk), jnp.float32)
         a0 = jnp.zeros((b, n_kv, g, q_chunk, dv), jnp.float32)
@@ -110,7 +122,7 @@ def flash_attention(
             m, l, acc = carry
         else:
             (m, l, acc), _ = jax.lax.scan(
-                body, (m0, l0, a0), jnp.arange(n_kv_chunks))
+                step, (m0, l0, a0), jnp.arange(n_kv_chunks))
         o = acc / jnp.maximum(l[..., None], 1e-37)
         # (B,N,G,Sq,Dv) -> (B,Sq,Hq,Dv)
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, q_chunk, hq, dv)

@@ -7,7 +7,8 @@ the cached latent + shared rope key, and W_UV is folded into the output
 projection — so the KV cache is (kv_lora + qk_rope) = 576 floats/token/layer
 instead of heads*(nope+rope+v) = 40960. That 71x cache shrink is the
 arch-level analog of the paper's in-situ data reduction, and is why this arch
-is the technique-representative hillclimb cell.
+is the technique-representative hillclimb cell. The query projection is
+low-rank (``q_lora``, DeepSeek-V3) or full-rank (``q_lora`` None, Moonlight).
 """
 from __future__ import annotations
 
@@ -34,10 +35,15 @@ def mla_spec(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
             axes = ("layers",) + axes
         return ParamSpec(shape, axes, **kw)
 
+    if m.q_lora:
+        q = {"wq_a": mk((d, m.q_lora), ("embed", "lora")),
+             "q_norm": mk((m.q_lora,), ("lora",), dtype=jnp.float32,
+                          init="ones"),
+             "wq_b": mk((m.q_lora, h, qk), ("lora", "heads", "head_dim"))}
+    else:
+        q = {"wq": mk((d, h, qk), ("embed", "heads", "head_dim"))}
     return {
-        "wq_a": mk((d, m.q_lora), ("embed", "lora")),
-        "q_norm": mk((m.q_lora,), ("lora",), dtype=jnp.float32, init="ones"),
-        "wq_b": mk((m.q_lora, h, qk), ("lora", "heads", "head_dim")),
+        **q,
         "wkv_a": mk((d, m.kv_lora + m.qk_rope), ("embed", "lora")),
         "kv_norm": mk((m.kv_lora,), ("lora",), dtype=jnp.float32, init="ones"),
         "wk_b": mk((m.kv_lora, h, m.qk_nope), ("lora", "heads", "head_dim")),
@@ -48,9 +54,12 @@ def mla_spec(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
 
 def _project_q(p, x, cfg: ModelConfig, positions):
     m = cfg.mla
-    q_lat = jnp.einsum("bsd,dl->bsl", x, p["wq_a"])
-    q_lat = rmsnorm({"scale": p["q_norm"]}, q_lat, cfg.norm_eps)
-    q = jnp.einsum("bsl,lhk->bshk", q_lat, p["wq_b"])
+    if m.q_lora:
+        q_lat = jnp.einsum("bsd,dl->bsl", x, p["wq_a"])
+        q_lat = rmsnorm({"scale": p["q_norm"]}, q_lat, cfg.norm_eps)
+        q = jnp.einsum("bsl,lhk->bshk", q_lat, p["wq_b"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., : m.qk_nope], q[..., m.qk_nope:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope
@@ -71,19 +80,21 @@ def mla_attention(p, x, cfg: ModelConfig, positions, *, q_chunk=None,
     """Training/prefill path: materialized per-head K/V, chunked flash."""
     m = cfg.mla
     b, s, _ = x.shape
-    q_nope, q_rope = _project_q(p, x, cfg, positions)
-    c_kv, k_rope = _project_kv_latent(p, x, cfg, positions)
-    k_nope = jnp.einsum("bsl,lhk->bshk", c_kv, p["wk_b"])
-    v = jnp.einsum("bsl,lhk->bshk", c_kv, p["wv_b"])
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (b, s, cfg.n_heads, m.qk_rope))], axis=-1)
-    o = attn_lib.flash_attention(
-        q, k, v, causal=True,
-        q_chunk=q_chunk or cfg.q_chunk, kv_chunk=kv_chunk or cfg.kv_chunk,
-        unroll=cfg.unroll_scans)
-    return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    with jax.named_scope("mla_attention"):
+        q_nope, q_rope = _project_q(p, x, cfg, positions)
+        c_kv, k_rope = _project_kv_latent(p, x, cfg, positions)
+        k_nope = jnp.einsum("bsl,lhk->bshk", c_kv, p["wk_b"])
+        v = jnp.einsum("bsl,lhk->bshk", c_kv, p["wv_b"])
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      (b, s, cfg.n_heads, m.qk_rope))],
+            axis=-1)
+        o = attn_lib.flash_attention(
+            q, k, v, causal=True,
+            q_chunk=q_chunk or cfg.q_chunk, kv_chunk=kv_chunk or cfg.kv_chunk,
+            unroll=cfg.unroll_scans)
+        return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
 
 
 def mla_decode(p, x, cfg: ModelConfig, cache_ckv, cache_krope, length):

@@ -1,17 +1,35 @@
-"""Capacity-based top-k MoE (GShard-style dispatch/combine einsums).
+"""Mixture-of-experts FFN: dropless routing over the experts a chip holds,
+or capacity-based top-k (GShard-style dispatch/combine einsums).
 
 Expert weights live stacked on an 'expert' axis that the sharding rules map to
 the 'model' mesh axis (expert parallelism); tokens stay sharded over the DP
-axes. XLA SPMD then materializes the all-to-all style exchange between the two
-shardings. Token streams are processed in fixed-size chunks (scan) so the
-(chunk, experts, capacity) dispatch tensor is bounded regardless of the global
-batch — e.g. deepseek train_4k: (2048, 256, 80) bf16 = 84 MB live, not the
-multi-GB unchunked version.
+axes.
 
-Routing: softmax router, top-k, per-chunk capacity C = ceil(chunk*k/E * cf).
-Overflow tokens drop (standard); the combine weights renormalize over the
-surviving experts. Aux load-balance + router-z losses are returned for the
-train loss.
+**Dropless** (``capacity_factor`` None; Moonlight, DeepSeek-V3's
+published routing): sigmoid scores over all ``n_experts``; the top-k of
+the scores plus a per-expert correction bias select the experts (the bias
+carries no gradient and moves only through :func:`update_bias`); the
+weights are the selected scores, normalised, times ``routed_scale``. The layer holds experts
+``[0, n_held)`` (one chip's share of an expert-parallel deployment): every
+(token, slot) routed to a held expert is computed, with no capacity, by a
+grouped matmul (``jax.lax.ragged_dot``) over the (token, slot) pairs sorted
+by expert, in O(T·k) memory. What the absent experts would add is left out;
+the shared experts are computed on every chip. A sequence-wise balance loss
+(``seq_aux_weight``) is returned for the train loss.
+
+**Capacity** (a number; the other MoE configurations): softmax router,
+top-k, per-chunk capacity C = ceil(chunk*k/E * cf). Token streams are
+processed in fixed-size chunks (scan) so the (chunk, experts, capacity)
+dispatch tensor is bounded regardless of the global batch — e.g. deepseek
+train_4k: (2048, 256, 80) bf16 = 84 MB live. Overflow tokens drop; the
+combine weights renormalize over the surviving experts. Aux load-balance +
+router-z losses are returned for the train loss.
+
+``moe_ffn`` returns ``(y, stats)``: ``stats["loss"]`` is the auxiliary
+loss; the dropless path adds ``load`` (tokens that selected each of the
+``n_experts``, for the bias update), ``routed`` ((token, slot) pairs the
+held experts computed) and ``max_load`` (the largest held expert's load
+over the balanced load T·k / n_experts).
 """
 from __future__ import annotations
 
@@ -35,12 +53,16 @@ def moe_spec(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
             axes = ("layers",) + axes
         return ParamSpec(shape, axes, **kw)
 
+    e = m.held
     spec = {
         "router": mk((d, m.n_experts), ("embed", "expert"), dtype=jnp.float32),
-        "w_gate": mk((m.n_experts, d, m.d_expert), ("expert", "embed", "e_mlp")),
-        "w_up": mk((m.n_experts, d, m.d_expert), ("expert", "embed", "e_mlp")),
-        "w_down": mk((m.n_experts, m.d_expert, d), ("expert", "e_mlp", "embed")),
+        "w_gate": mk((e, d, m.d_expert), ("expert", "embed", "e_mlp")),
+        "w_up": mk((e, d, m.d_expert), ("expert", "embed", "e_mlp")),
+        "w_down": mk((e, m.d_expert, d), ("expert", "e_mlp", "embed")),
     }
+    if m.bias_update_rate:
+        spec["e_bias"] = mk((m.n_experts,), ("expert",), dtype=jnp.float32,
+                            init="zeros")
     if m.n_shared_experts:
         ds = m.d_expert * m.n_shared_experts
         spec.update({
@@ -50,6 +72,111 @@ def moe_spec(cfg: ModelConfig, layers: Optional[int] = None) -> dict:
         })
     return spec
 
+
+# ---------------------------------------------------------------------------
+# dropless routing over the held experts
+# ---------------------------------------------------------------------------
+
+def select(router_w, bias, x, m: MoEConfig):
+    """Sigmoid scores over all experts and the top-k selection.
+
+    x: (..., D). Returns (scores (..., E) f32, top_e (..., k), top_w (..., k)
+    f32): experts by score plus ``bias`` (selection only), weights from the
+    scores alone, normalised and scaled."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), router_w)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, top_e = jax.lax.top_k(choice, m.top_k)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)
+    return scores, top_e, top_w * m.routed_scale
+
+
+def seq_balance_loss(scores, top_e, m: MoEConfig):
+    """DeepSeek-V3's sequence-wise balance loss, alpha x sum_i f_i P_i per
+    sequence, averaged over sequences. scores (B, S, E), top_e (B, S, k)."""
+    e, k, s = m.n_experts, m.top_k, scores.shape[1]
+    picked = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.float32), axis=-2)
+    f = jnp.sum(picked, axis=1) * (e / (k * s))                     # (B, E)
+    p = jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=1)
+    return m.seq_aux_weight * jnp.mean(jnp.sum(f * p, axis=-1))
+
+
+def routed_experts(p, x2d, top_e, top_w, first: int = 0):
+    """The held experts' part of the layer, dropless.
+
+    x2d (T, D); top_e, top_w (T, k) over global expert ids; the layer holds
+    experts ``[first, first + held)`` (``p["w_gate"]`` etc. stacked on the
+    held axis). Every (token, slot) routed to a held expert is computed:
+    the pairs are sorted by expert, gathered, run through a grouped matmul
+    per held expert and combined in float32. Returns (y (T, D),
+    group sizes (held,))."""
+    t, k = top_e.shape
+    held = p["w_gate"].shape[0]
+    local = top_e.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)             # held pairs first
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    # rows past the held pairs belong to no group: a grouped matmul leaves
+    # them unwritten (on the TPU, whatever the buffer held), forward and in
+    # the gradient of its input, so they are zeroed on both sides
+    valid = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(valid, x2d[order // k], jnp.zeros((), x2d.dtype))
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
+        u = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+        out = jax.lax.ragged_dot(h, p["w_down"], sizes)
+    with jax.named_scope("moe_combine"):
+        # put every pair back in (token, slot) order and weigh it
+        out = jnp.where(valid, out, jnp.zeros((), out.dtype))
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+        pairs = out[back].reshape(t, k, -1)
+        y = jnp.einsum("tkd,tk->td", pairs, top_w.astype(pairs.dtype),
+                       preferred_element_type=jnp.float32)
+    return y.astype(x2d.dtype), sizes
+
+
+def shared_experts(p, x):
+    g = jnp.einsum("...d,df->...f", x, p["shared_gate"])
+    u = jnp.einsum("...d,df->...f", x, p["shared_up"])
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    return jnp.einsum("...f,fd->...d", h, p["shared_down"])
+
+
+def _dropless_ffn(p, x, cfg: ModelConfig):
+    m = cfg.moe
+    b, s, d = x.shape
+    with jax.named_scope("moe_routing"):
+        scores, top_e, top_w = select(p["router"], p.get("e_bias"), x, m)
+        loss = (seq_balance_loss(scores, top_e, m) if m.seq_aux_weight
+                else jnp.zeros((), jnp.float32))
+        load = jnp.sum(jax.nn.one_hot(top_e, m.n_experts, dtype=jnp.float32),
+                       axis=(0, 1, 2))
+    y, sizes = routed_experts(p, x.reshape(-1, d), top_e.reshape(-1, m.top_k),
+                              top_w.reshape(-1, m.top_k))
+    y = y.reshape(b, s, d)
+    if m.n_shared_experts:
+        y = y + shared_experts(p, x)
+    balanced = b * s * m.top_k / m.n_experts
+    stats = {"loss": loss, "load": load,
+             "routed": jnp.sum(sizes).astype(jnp.float32),
+             "max_load": jnp.max(sizes).astype(jnp.float32) / balanced}
+    return y, stats
+
+
+def update_bias(bias, load, rate: float):
+    """Auxiliary-loss-free balancing: each expert's bias moves by ``rate``
+    toward balance, down if it took more than the mean load, up if less.
+    bias, load (..., E); the load is counted over the tokens this chip
+    routed."""
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - load)
+
+
+# ---------------------------------------------------------------------------
+# capacity-based dispatch
+# ---------------------------------------------------------------------------
 
 def _expert_ffn(p, x_d: jax.Array) -> jax.Array:
     """x_d: (E, C, D) -> (E, C, D), per-expert SwiGLU."""
@@ -156,8 +283,12 @@ def _grouped_body(p, xc: jax.Array, cfg: ModelConfig):
 
 
 def moe_ffn(p, x: jax.Array, cfg: ModelConfig):
-    """x: (B, S, D) -> (y, aux_loss). Token stream chunk-scanned."""
+    """x: (B, S, D) -> (y, stats); see the module docstring."""
     m = cfg.moe
+    if m.capacity_factor is None:
+        return _dropless_ffn(p, x, cfg)
+    if m.held != m.n_experts:
+        raise ValueError("a share of the experts needs dropless routing")
     b, s, d = x.shape
     tokens = x.reshape(-1, d)
     t = tokens.shape[0]
@@ -191,11 +322,8 @@ def moe_ffn(p, x: jax.Array, cfg: ModelConfig):
         aux, y = jax.lax.scan(body, jnp.zeros((), jnp.float32), tokens)
     y = y.reshape(b, s, d)
     if m.n_shared_experts:
-        g = jnp.einsum("bsd,df->bsf", x, p["shared_gate"])
-        u = jnp.einsum("bsd,df->bsf", x, p["shared_up"])
-        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-        y = y + jnp.einsum("bsf,fd->bsd", h, p["shared_down"])
-    return y, aux / n_chunks
+        y = y + shared_experts(p, x)
+    return y, {"loss": aux / n_chunks}
 
 
 def _ep_constraint(x_ecd):

@@ -223,21 +223,17 @@ def _scan_blocks(stacked, x, body, cfg, n: int):
 
 
 def _scan_blocks_aux(stacked, x, body, cfg, n: int):
+    """Like ``_scan_blocks`` for blocks that return ``(x, stats)``: the
+    per-layer stats come back stacked on a leading layer axis."""
     fn = jax.checkpoint(body) if cfg.remat else body
 
-    def step(carry, layer_params):
-        x, aux = carry
-        x, a = fn(x, layer_params)
-        return (x, aux + a), None
-
     if not cfg.scan_layers:
-        aux = jnp.zeros((), jnp.float32)
+        outs = []
         for i in range(n):
-            x, a = fn(x, jax.tree.map(lambda t: t[i], stacked))
-            aux = aux + a
-        return x, aux
-    (x, aux), _ = jax.lax.scan(step, (x, jnp.zeros((), jnp.float32)), stacked)
-    return x, aux
+            x, st = fn(x, jax.tree.map(lambda t: t[i], stacked))
+            outs.append(st)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+    return jax.lax.scan(fn, x, stacked)
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +244,9 @@ def forward(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
             prefix_embeds: Optional[jax.Array] = None,
             q_chunk: Optional[int] = None, kv_chunk: Optional[int] = None,
             bspec=None, h0: Optional[jax.Array] = None):
-    """tokens: (B, S_tok) int32. Returns (logits, aux_loss, loss_mask).
+    """tokens: (B, S_tok) int32. Returns (logits, aux, loss_mask): ``aux``
+    is ``{"loss": auxiliary loss}`` plus, for MoE layers, their per-layer
+    routing stats stacked (``moe.moe_ffn``).
 
     ``h0`` (optional) is a precomputed token embedding — used by the pod-ring
     train step, which hoists the embedding gather out of its manual region.
@@ -261,7 +259,7 @@ def forward(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
         h = jax.lax.with_sharding_constraint(h, bspec)
     b, s, _ = h.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-    aux = jnp.zeros((), jnp.float32)
+    aux = {"loss": jnp.zeros((), jnp.float32)}
 
     if cfg.family in ("dense", "audio", "vlm"):
         body = functools.partial(
@@ -280,6 +278,7 @@ def forward(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
                                         q_chunk=q_chunk, kv_chunk=kv_chunk)
         h, aux = _scan_blocks_aux(params["moe_blocks"], h, body_m, cfg,
                                   cfg.n_layers - m.first_dense)
+        aux = dict(aux, loss=jnp.sum(aux["loss"]))
 
     elif cfg.family == "hybrid":
         h = _hybrid_forward(params, cfg, h, positions, q_chunk, kv_chunk)
@@ -362,9 +361,16 @@ def _xlstm_forward(params, cfg, h):
 # Train loss
 # ---------------------------------------------------------------------------
 
-def train_loss(params: PyTree, cfg: ModelConfig, batch: dict, *, bspec=None,
-               q_chunk=None, kv_chunk=None, h0=None, mtp_pre=None,
-               gather_free: bool = False) -> jax.Array:
+def train_loss(params: PyTree, cfg: ModelConfig, batch: dict,
+               **kw) -> jax.Array:
+    return train_loss_and_stats(params, cfg, batch, **kw)[0]
+
+
+def train_loss_and_stats(params: PyTree, cfg: ModelConfig, batch: dict, *,
+                         bspec=None, q_chunk=None, kv_chunk=None, h0=None,
+                         mtp_pre=None, gather_free: bool = False):
+    """(loss, stats): the train loss and the MoE layers' routing stats
+    (``forward``'s ``aux`` without its loss; empty for other families)."""
     logits, aux, fmask = forward(
         params, cfg, batch["tokens"], prefix_embeds=batch.get("prefix"),
         q_chunk=q_chunk, kv_chunk=kv_chunk, bspec=bspec, h0=h0)
@@ -386,7 +392,8 @@ def train_loss(params: PyTree, cfg: ModelConfig, batch: dict, *, bspec=None,
         loss = loss + cfg.mtp_weight * _mtp_loss(
             params, cfg, logits, batch, mtp_pre=mtp_pre,
             gather_free=gather_free)
-    return loss + aux
+    stats = {k: v for k, v in aux.items() if k != "loss"}
+    return loss + aux["loss"], stats
 
 
 def _mtp_loss(params, cfg, logits, batch, mtp_pre=None, gather_free=False):

@@ -169,6 +169,51 @@ def test_fused_tree_trace_cache_buckets_shapes():
                                       np.asarray(plain[key].scale))
 
 
+def test_grouped_launches_bit_identical_to_one_launch(monkeypatch):
+    """A tree over the launch budget compresses in several fused launches,
+    each under it, bit-identical leaf by leaf to the single launch."""
+    rng = np.random.default_rng(5)
+    state = {"w": jnp.ones((8, 8), jnp.float32),
+             "opt": {f"m{i}": jnp.asarray(rng.standard_normal(n)
+                                          .astype(np.float32))
+                     for i, n in enumerate((5000, 20000, 777, 9000, 3000))}}
+    policy = lambda k: "opt" in k   # noqa: E731
+    assert ops.compress_launches(state, policy) == 1
+    one = ops.spectral_compress_tree(state, 1e-2, policy)
+    budget = 128 * ref.BLOCK * 4
+    monkeypatch.setattr(ops, "COMPRESS_LAUNCH_BYTES", budget)
+    launched, packed = [], ops._compress_tree_packed
+
+    def count(blocks, *args):
+        launched.append(sum(b.size for b in blocks) * 4)
+        return packed(blocks, *args)
+
+    monkeypatch.setattr(ops, "_compress_tree_packed", count)
+    grouped = ops.spectral_compress_tree(state, 1e-2, policy)
+    assert len(launched) == ops.compress_launches(state, policy) >= 3
+    assert max(launched) <= budget
+    for key, c in one["opt"].items():
+        g = grouped["opt"][key]
+        np.testing.assert_array_equal(np.asarray(g.q), np.asarray(c.q))
+        np.testing.assert_array_equal(np.asarray(g.scale),
+                                      np.asarray(c.scale))
+    assert grouped["w"] is state["w"]
+
+
+def test_launch_budget_keeps_smollm_in_one_launch():
+    """smollm-135m's moments compress in one launch; the one-chip cut of
+    Moonlight-16B-A3B (4.5 GB of f32 blocks) does not."""
+    from repro.checkpoint.manager import default_lossy_policy
+    from repro.configs import base
+    from repro.launch import train
+    small = train.state_spec(base.get("smollm-135m"))
+    assert ops.compress_launches(small, default_lossy_policy) == 1
+    cut = base.chip_share(base.get("moonlight-16b-a3b"), n_layers=5,
+                          n_held=8, vocab_size=20480)
+    assert ops.compress_launches(train.state_spec(cut),
+                                 default_lossy_policy) > 1
+
+
 def test_fused_tree_bit_equal_to_per_leaf():
     """Tentpole contract: the single-dispatch fused tree compression is
     bit-identical to the per-leaf path, leaf by leaf."""

@@ -30,7 +30,7 @@ from repro.serving import pages as PG
 from repro.serving import prefix as PX
 from repro.serving.engine import Request
 
-SHAREABLE_ARCHS = ["smollm-135m", "deepseek-v3-671b", "moonshot-v1-16b-a3b"]
+SHAREABLE_ARCHS = ["smollm-135m", "deepseek-v3-671b", "moonlight-16b-a3b"]
 STATEFUL_ARCHS = ["hymba-1.5b", "xlstm-1.3b"]
 
 

@@ -69,7 +69,7 @@ def test_report_bottleneck_and_fraction():
 
 def test_model_flops_moe_counts_active_only():
     dense = base.get("granite-3-2b")
-    moe = base.get("moonshot-v1-16b-a3b")
+    moe = base.get("moonlight-16b-a3b")
     shape = base.SHAPES["train_4k"]
     f_moe = R.model_flops(moe, shape)
     # MoE: active params far fewer than total

@@ -12,7 +12,7 @@ from repro.serving import engine as E
 from repro.serving import kvcache
 
 DECODE_ARCHS = ["smollm-135m", "qwen1.5-110b", "deepseek-v3-671b",
-                "moonshot-v1-16b-a3b", "hymba-1.5b", "xlstm-1.3b",
+                "moonlight-16b-a3b", "hymba-1.5b", "xlstm-1.3b",
                 "musicgen-medium"]
 
 

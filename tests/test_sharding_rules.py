@@ -1,4 +1,6 @@
 """Logical-axis -> PartitionSpec rules (AbstractMesh: no devices needed)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -91,6 +93,7 @@ def test_cache_seq_fallback_for_indivisible_kv_heads():
 
 
 def test_cache_kv_heads_shard_when_divisible():
-    cfg = base.get("moonshot-v1-16b-a3b")  # kv=16 == model
+    # a GQA cache with kv=16 == model
+    cfg = dataclasses.replace(base.get("granite-3-2b"), n_kv_heads=16)
     specs = kvcache.cache_partition_spec(cfg, 128, 32768, MESH)
     assert specs["kv"]["k"][3] == "model"

@@ -109,3 +109,24 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, dtype):
             _spec(one_chip, (b,), jnp.int32))
     fn = functools.partial(PK.paged_decode_attention, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, *args)
+
+
+def test_grouped_expert_matmuls_compile_for_v5e(one_chip):
+    """Moonlight-16B-A3B's dropless expert layer at its widths (16,384
+    tokens, top-6, 8 held experts of d 2048 x 1408), forward and backward:
+    the grouped matmuls lower to the TPU's ragged-dot kernels."""
+    from repro.models import moe as moe_lib
+    t, k, e, d, f = 16384, 6, 8, 2048, 1408
+    p = {"w_gate": _spec(one_chip, (e, d, f), jnp.bfloat16),
+         "w_up": _spec(one_chip, (e, d, f), jnp.bfloat16),
+         "w_down": _spec(one_chip, (e, f, d), jnp.bfloat16)}
+    args = (p, _spec(one_chip, (t, d), jnp.bfloat16),
+            _spec(one_chip, (t, k), jnp.int32),
+            _spec(one_chip, (t, k), jnp.float32))
+
+    def loss(p, x, top_e, top_w):
+        y, _ = moe_lib.routed_experts(p, x, top_e, top_w)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)), *args)
+    assert "tpu_custom_call" in text and "ragged-dot" in text
